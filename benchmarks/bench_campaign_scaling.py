@@ -10,9 +10,10 @@ the ``launch/dryrun.py`` pattern) runs 8·L lanes under a
 ``tests/test_campaign_sharded.py``).  **Weak scaling** = total lanes/s vs
 the single-device engine; the acceptance floor is ≥ 4x at 8 devices.
 
-Every measurement runs in a fresh subprocess: XLA_FLAGS must be set before
-jax imports, timings must include compile (a sweep is a one-shot program),
-and the parent may already hold a single-device jax (benchmarks/run.py).
+Every measurement runs in a fresh CPU subprocess: XLA_FLAGS must be set
+before jax imports, timings must include compile (a sweep is a one-shot
+program), and the fake devices are the host platform's — never a chip.
+These are CPU figures, not a chip measurement.
 
 CLI:  ``python benchmarks/bench_campaign_scaling.py [--tiny] [--json F]``
 """
@@ -90,6 +91,7 @@ def _measure(devices: int, lanes: int, *, rounds: int, n_params: int,
            "n_params": n_params, "nodes": nodes, "model": model}
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"             # fake host devices, never a chip
     proc = subprocess.run([sys.executable, "-c", _WORKER, json.dumps(cfg)],
                           capture_output=True, text=True, timeout=600,
                           env=env)

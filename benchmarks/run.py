@@ -20,11 +20,9 @@ Prints ``name,us_per_call,derived`` CSV rows.
 """
 from __future__ import annotations
 
+import subprocess
 import sys
 import time
-import traceback
-
-from benchmarks.common import emit
 
 MODULES = [
     "bench_capacity",
@@ -44,23 +42,33 @@ MODULES = [
 ]
 
 
+# Each module runs in a process of its own: a device belongs to one process
+# at a time, so this runner never imports jax, and no module inherits a
+# device another one holds.
+_CHILD = (
+    "import sys\n"
+    "from repro.launch import compile_cache\n"
+    "from benchmarks.common import emit\n"
+    "compile_cache.enable()\n"
+    "mod = __import__('benchmarks.' + sys.argv[1], fromlist=['run'])\n"
+    "emit(mod.run())\n")
+
+
 def main(argv=None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
     selected = argv or MODULES
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived", flush=True)
     failures = 0
     for name in selected:
         mod_name = name if name.startswith("bench_") else f"bench_{name}"
         t0 = time.time()
-        try:
-            mod = __import__(f"benchmarks.{mod_name}", fromlist=["run"])
-            emit(mod.run())
+        rc = subprocess.run([sys.executable, "-c", _CHILD, mod_name]).returncode
+        if rc:
+            failures += 1
+            print(f"# {mod_name} FAILED (exit {rc})", file=sys.stderr)
+        else:
             print(f"# {mod_name} done in {time.time() - t0:.1f}s",
                   file=sys.stderr)
-        except Exception:
-            failures += 1
-            print(f"# {mod_name} FAILED", file=sys.stderr)
-            traceback.print_exc()
     return 1 if failures else 0
 
 

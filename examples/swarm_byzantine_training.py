@@ -58,6 +58,44 @@ def showcase_roster(rounds: int):
     return nodes, cfg
 
 
+def build_lm(full: bool):
+    """(cfg, model): protocol-125m at its published width, or the reduced
+    4-layer variant the CPU default trains."""
+    cfg = get_config("protocol-125m")
+    if not full:
+        cfg = cfg.reduced(num_layers=4, d_model=256, num_heads=4,
+                          head_dim=64, d_ff=1024, vocab_size=2048)
+    return cfg, build_model(cfg)
+
+
+def build_roster(scenario: str, rounds: int, n_nodes: int):
+    """(nodes, SwarmConfig) for the showcase roster or a registry scenario
+    at ``n_nodes`` (the showcase roster has a fixed size)."""
+    if scenario == "showcase":
+        return showcase_roster(rounds)
+    return get_scenario(scenario).build(n_nodes=n_nodes)
+
+
+def build_swarm(cfg, model, nodes, swarm_cfg, *, engine: str = "batched"):
+    """(swarm, eval_fn): the LM swarm over the synthetic data pipeline —
+    two sequences of 128 tokens per node per round, AdamW, seed 0."""
+    n_nodes = len(nodes)
+    dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=128,
+                      global_batch=n_nodes * 2)
+    params = model.init(jax.random.PRNGKey(0))
+    opt = AdamW(lr=5e-3)
+    loss_fn = lambda p, b: model.loss(p, b)[0]
+    data_fn = data_fn_for_swarm(cfg, dcfg, n_nodes)
+    # the synthetic pipeline is jax-pure in the node index, so the batched
+    # engine can build all N node batches in a single vmapped dispatch
+    bdf = (batched_data_fn_for(data_fn, n_nodes)
+           if engine == "batched" else None)
+    swarm = make_swarm(loss_fn, params, opt, nodes, swarm_cfg, data_fn,
+                       engine=engine, batched_data_fn=bdf)
+    eval_fn = lambda p: loss_fn(p, model_batch(cfg, dcfg, 10**6))
+    return swarm, eval_fn
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=200)
@@ -72,34 +110,15 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/repro_swarm_custody_ckpt")
     args = ap.parse_args()
 
-    cfg = get_config("protocol-125m")
-    if not args.full:
-        cfg = cfg.reduced(num_layers=4, d_model=256, num_heads=4,
-                          head_dim=64, d_ff=1024, vocab_size=2048)
-    model = build_model(cfg)
+    cfg, model = build_lm(args.full)
     print(f"model: {cfg.name} N={model.cfg.param_count():,} "
           f"({'full' if args.full else 'reduced'})")
 
-    if args.scenario == "showcase":
-        nodes, swarm_cfg = showcase_roster(args.rounds)
-    else:
-        nodes, swarm_cfg = get_scenario(args.scenario).build(n_nodes=args.nodes)
-    n_nodes = len(nodes)
-    print(f"scenario: {args.scenario} ({n_nodes} nodes, engine={args.engine})")
-
-    dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=128,
-                      global_batch=n_nodes * 2)
-    params = model.init(jax.random.PRNGKey(0))
-    opt = AdamW(lr=5e-3)
-    loss_fn = lambda p, b: model.loss(p, b)[0]
-    data_fn = data_fn_for_swarm(cfg, dcfg, n_nodes)
-    # the synthetic pipeline is jax-pure in the node index, so the batched
-    # engine can build all N node batches in a single vmapped dispatch
-    bdf = (batched_data_fn_for(data_fn, n_nodes)
-           if args.engine == "batched" else None)
-    swarm = make_swarm(loss_fn, params, opt, nodes, swarm_cfg, data_fn,
-                       engine=args.engine, batched_data_fn=bdf)
-    eval_fn = lambda p: loss_fn(p, model_batch(cfg, dcfg, 10**6))
+    nodes, swarm_cfg = build_roster(args.scenario, args.rounds, args.nodes)
+    print(f"scenario: {args.scenario} ({len(nodes)} nodes, "
+          f"engine={args.engine})")
+    swarm, eval_fn = build_swarm(cfg, model, nodes, swarm_cfg,
+                                 engine=args.engine)
 
     t0 = time.time()
     print(f"{'round':>6} {'active':>6} {'byz':>4} {'loss':>8}  slashed")
@@ -142,4 +161,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

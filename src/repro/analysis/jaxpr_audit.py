@@ -34,6 +34,7 @@ import hashlib
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
+import jax.extend
 
 from repro.analysis import programs as programs_mod
 from repro.analysis.programs import DonationUnit, TracedProgram, TracedUnit
@@ -67,9 +68,9 @@ def _sub_jaxprs(params: dict) -> Iterator:
 
 
 def _as_jaxprs(v) -> Iterator:
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax.extend.core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax.extend.core.Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for x in v:
@@ -95,7 +96,7 @@ def _aval_dtype(aval) -> str:
 # ---------------------------------------------------------------------------
 # fingerprint (JX007)
 # ---------------------------------------------------------------------------
-def fingerprint(closed: jax.core.ClosedJaxpr) -> str:
+def fingerprint(closed: jax.extend.core.ClosedJaxpr) -> str:
     """Structural digest of a traced program: input/output avals, const
     avals, and the recursive (primitive, output-aval) sequence.  Equation
     *params* are deliberately excluded — they embed device-dependent

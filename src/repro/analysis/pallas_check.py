@@ -20,12 +20,13 @@ with python ints.
   double-buffered, plus scratch — must fit the per-kernel budget,
   default :data:`repro.launch.roofline.VMEM_BYTES` (the same constant the
   roofline model uses, so the two can never drift apart).
-- **PK004** a *tiled* trailing (feature) dim must stay lane-multiple: if a
-  block tiles the last axis of an array whose trailing dim is >= one lane
-  (128), the block's trailing extent must be a multiple of 128 — the
-  padding contract ``masked_agg._pad_lanes`` exists to guarantee.
-  Sub-lane arrays (e.g. per-bucket norms) are out of scope by
-  construction, not exemption.
+- **PK004** Mosaic's tiling rule on the last two block dims: the trailing
+  extent must be a multiple of 128 (the lane width) or equal the array's
+  trailing dim, and the second-to-last a multiple of 8 (the sublane
+  count) or equal the array's — the padding contract
+  ``masked_agg._pad_lanes`` exists to guarantee.  Sub-lane arrays are in
+  scope: a per-bucket norms block of 8 over a trailing dim of D/512 is
+  refused by the TPU compiler, and by this rule.
 """
 from __future__ import annotations
 
@@ -42,6 +43,7 @@ from repro.analysis.report import Violation
 from repro.launch.roofline import VMEM_BYTES
 
 LANE = 128
+SUBLANE = 8
 _GRID_POINT_CAP = 65536          # probes are tiny; a blowup is a probe bug
 
 
@@ -206,14 +208,22 @@ def _check_call(call: CapturedCall,
                     f"{len(missing)}/{len(required)} output tiles never "
                     f"visited, e.g. {sorted(missing)[0]} (grid "
                     f"{call.grid}, block {block}, array {shape})"))
-        # PK004 — lane contract on tiled feature dims
+        # PK004 — Mosaic's (8, 128) tiling rule on the last two dims
         bt, at = block[-1], shape[-1]
-        if bt < at and at >= LANE and bt % LANE:
+        if bt != at and bt % LANE:
             out.append(Violation(
                 "PK004", where,
-                f"trailing dim tiled {bt}/{at}: tile is not a multiple "
-                f"of the {LANE}-wide lane (pad the array — see "
-                "masked_agg._pad_lanes)"))
+                f"trailing dim tiled {bt}/{at}: tile is neither a "
+                f"multiple of the {LANE}-wide lane nor the full dim (pad "
+                "the array — see masked_agg._pad_lanes)"))
+        if len(block) >= 2:
+            bs, as_ = block[-2], shape[-2]
+            if bs != as_ and bs % SUBLANE:
+                out.append(Violation(
+                    "PK004", where,
+                    f"second-to-last dim tiled {bs}/{as_}: tile is "
+                    f"neither a multiple of {SUBLANE} sublanes nor the "
+                    "full dim"))
     return out
 
 

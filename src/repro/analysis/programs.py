@@ -3,7 +3,8 @@
 This module builds the *real* entry-point programs of the engine (the same
 builders ``run_campaign`` / ``derailment.sweep`` / ``ServingEngine`` execute
 — not reimplementations that could drift) against tiny probe problems, and
-hands ``jaxpr_audit`` their :class:`jax.core.ClosedJaxpr`.  Seven programs:
+hands ``jaxpr_audit`` their :class:`jax.extend.core.ClosedJaxpr`.
+Seven programs:
 
 ``round_unfused`` / ``round_fused``
     ``swarm.make_round_fn`` in both hot-path modes, plus the scanned-run
@@ -46,6 +47,7 @@ from typing import Callable, Dict, FrozenSet, List, Optional, Tuple
 import numpy as np
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from repro.core import derailment, economy, serving, swarm
@@ -69,7 +71,7 @@ class TracedUnit:
     collectives may legally use (JX005); empty = no collectives allowed.
     """
     label: str
-    closed: jax.core.ClosedJaxpr
+    closed: jax.extend.core.ClosedJaxpr
     group: Optional[str] = None
     declared_axes: FrozenSet[str] = frozenset()
 

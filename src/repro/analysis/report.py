@@ -36,7 +36,7 @@ RULES: Dict[str, str] = {
     "PK001": "kernel output tiles do not cover the output array",
     "PK002": "kernel tile reads/writes past the padded array bounds",
     "PK003": "kernel VMEM tile footprint exceeds its budget",
-    "PK004": "tiled feature dim violates the lane-multiple padding contract",
+    "PK004": "block violates Mosaic's (8, 128) tiling rule on its last two dims",
     # -- tracer_lint ----------------------------------------------------------
     "PL000": "stale baseline entry (key no longer fires)",
     "PL001": "python if/while on a traced expression inside a traced fn",

@@ -50,8 +50,7 @@ def qsgd_compress(key, x: Array, *, levels: int = 16,
     """
     shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)
-    pad = (-flat.size) % bucket_size
-    padded = jnp.pad(flat, (0, pad)).reshape(-1, bucket_size)
+    padded = bucketed(flat, bucket_size)
     norms = jnp.linalg.norm(padded, axis=1, keepdims=True)   # (nb, 1)
     scaled = jnp.abs(padded) / jnp.maximum(norms, 1e-30) * levels
     lower = jnp.floor(scaled)
@@ -66,10 +65,29 @@ def qsgd_compress(key, x: Array, *, levels: int = 16,
         kind="qsgd",
         payload={"q": q, "sign": sign, "norms": norms, "levels": levels,
                  "size": flat.size},
-        bits=32 * norms.size + flat.size * bits_per_el,
+        bits=32 * -(-flat.size // bucket_size) + flat.size * bits_per_el,
         orig_shape=shape,
         orig_bits=_nbits(x),
     )
+
+
+#: bucket rows are padded to a multiple of this (the TPU sublane count)
+BUCKET_ROW_MULTIPLE = 8
+
+
+def bucketed(flat: Array, bucket_size: int) -> Array:
+    """Zero-pad a flat vector into (nb, bucket_size) rows, nb a multiple of
+    :data:`BUCKET_ROW_MULTIPLE`.  The extra all-zero buckets change no
+    result: their norms are 0 and their codes decode to 0, and threefry
+    draws over the padded shape extend those over the unpadded one (jax's
+    default partitionable threefry indexes draws by flat position).
+    Without the row padding the TPU compiler emits code for the per-bucket
+    norm reduction that grows with the bucket count — hundreds of MB at
+    100M+ elements."""
+    rows = -(-flat.size // bucket_size)
+    rows = -(-rows // BUCKET_ROW_MULTIPLE) * BUCKET_ROW_MULTIPLE
+    return jnp.pad(flat, (0, rows * bucket_size - flat.size)).reshape(
+        rows, bucket_size)
 
 
 def qsgd_decompress(c: Compressed) -> Array:

@@ -23,18 +23,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import aggregation
 
 Array = jax.Array
 
 
-def dense_sync(grads, axis: str, *, pod_index=None):
-    del pod_index                                # pmean needs no emulation
+def dense_sync(grads, axis: str):
     return jax.tree.map(lambda g: jax.lax.pmean(g, axis), grads)
 
 
-def qsgd_sync(grads, axis: str, *, bits: int = 8, pod_index=None):
+def qsgd_sync(grads, axis: str, *, bits: int = 8):
     """Quantize-then-all-gather: int8 on the wire, fp32 result."""
     qmax = 2 ** (bits - 1) - 1
 
@@ -42,8 +40,8 @@ def qsgd_sync(grads, axis: str, *, bits: int = 8, pod_index=None):
         gf = g.astype(jnp.float32)
         scale = jnp.max(jnp.abs(gf)) / qmax + 1e-30
         q = jnp.clip(jnp.round(gf / scale), -qmax, qmax).astype(jnp.int8)
-        qs = compat.all_gather(q, axis, index=pod_index)     # int8 on the wire
-        ss = compat.all_gather(scale.reshape(1), axis, index=pod_index)
+        qs = jax.lax.all_gather(q, axis)                     # int8 on the wire
+        ss = jax.lax.all_gather(scale.reshape(1), axis)
         deq = qs.astype(jnp.float32) * ss.reshape((-1,) + (1,) * gf.ndim)
         return jnp.mean(deq, axis=0).astype(g.dtype)
 
@@ -51,42 +49,41 @@ def qsgd_sync(grads, axis: str, *, bits: int = 8, pod_index=None):
 
 
 def centered_clip_sync(grads, axis: str, *, clip_tau: float | None = None,
-                       iters: int = 3, pod_index=None):
+                       iters: int = 3):
     """Byzantine-robust cross-pod aggregation: every pod is a 'node'."""
     return robust_sync(grads, axis, aggregator="centered_clip",
-                       clip_tau=clip_tau, iters=iters, pod_index=pod_index)
+                       clip_tau=clip_tau, iters=iters)
 
 
 def robust_sync(grads, axis: str, *, aggregator: str = "centered_clip",
-                pod_index=None, **kw):
+                **kw):
     """All-gather per-pod updates over ``axis`` and apply ANY robust
     aggregator from core.aggregation (median / trimmed_mean / krum / CC).
     The gather is the measured 'price of byzantine tolerance' on the pod
     axis (EXPERIMENTS.md §Perf pair C)."""
     stacked = jax.tree.map(
-        lambda g: compat.all_gather(g.astype(jnp.float32), axis,
-                                    index=pod_index), grads)
+        lambda g: jax.lax.all_gather(g.astype(jnp.float32), axis), grads)
     agg = aggregation.get_aggregator(aggregator, **kw)(stacked)
     return jax.tree.map(lambda a, g: a.astype(g.dtype), agg, grads)
 
 
-def median_sync(grads, axis: str, *, pod_index=None):
-    return robust_sync(grads, axis, aggregator="median", pod_index=pod_index)
+def median_sync(grads, axis: str):
+    return robust_sync(grads, axis, aggregator="median")
 
 
-def gossip_sync(grads, axis: str, *, rounds: int = 1, pod_index=None):
+def gossip_sync(grads, axis: str, *, rounds: int = 1):
     """Ring gossip: each round averages with both ring neighbours."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     fwd = [(i, (i + 1) % n) for i in range(n)]
     bwd = [(i, (i - 1) % n) for i in range(n)]
 
     def one_round(g):
         def per_leaf(x):
             xf = x.astype(jnp.float32)
-            right = compat.ppermute(xf, axis, fwd, index=pod_index)
+            right = jax.lax.ppermute(xf, axis, fwd)
             if n == 2:
                 return ((xf + right) / 2).astype(x.dtype)
-            left = compat.ppermute(xf, axis, bwd, index=pod_index)
+            left = jax.lax.ppermute(xf, axis, bwd)
             return ((xf + left + right) / 3).astype(x.dtype)
         return jax.tree.map(per_leaf, g)
 

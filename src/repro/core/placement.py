@@ -14,25 +14,23 @@ Two sharding levels, with different exactness contracts:
   axis over ``lanes`` (``place_lanes``), and the engine's ``vmap`` carries
   ``spmd_axis_name`` so internal sharding constraints stay lane-local.
   Lanes are embarrassingly parallel, so this is **bit-exact** against the
-  unsharded engine for the centralized, fused-kernel, and serving rounds
-  (pinned in ``tests/test_campaign_sharded.py``): every params/opt-state
-  leaf and every per-round counter.  Two ULP-level exceptions, both from
-  XLA making different fusion decisions under a mesh (which reorders float
+  unsharded engine run on each device's block of lanes, for the
+  centralized, fused-kernel, and serving rounds (pinned in
+  ``tests/test_campaign_sharded.py``): every params/opt-state leaf and
+  every per-round record.  Two ULP-level exceptions, both from XLA making
+  different fusion decisions under a mesh (which reorders float
   reductions): the final *eval* matmul, and the decentralized round's
-  gossip mixing matmul — those are allclose, not bit-equal.
+  gossip mixing matmul — those are allclose, not bit-equal.  Against one
+  unsharded program over *all* lanes the counters are equal and every
+  float within one bf16 ULP once each device holds ≥8 lanes; with fewer,
+  the TPU tiles a (lanes, D) array by the lane count and per-lane sums
+  over D round differently (``docs/scaling.md``).
 - **within-lane axes** — ``place_params`` shards a lane's *shared* params
   over ``model`` (and ``data``): via the symbolic rules in
   ``models.sharding.param_pspecs`` when the plan carries a
   :class:`~repro.configs.base.ModelConfig`, else a generic
   largest-divisible-dim rule for toy pytrees.  Resharding changes
   reduction order, so this level is **allclose-pinned** only.
-
-Old-jax caveat: this container's jax (0.4.x) emulates collectives
-(``compat.collectives_emulated()``) — plain GSPMD propagation, which is all
-a MeshPlan needs, lowers fine, but any program whose partitioning requires
-gather/permute collectives inside a partial-manual region hard-aborts.
-``reraise_lowering`` converts that abort into a clear error naming the
-predicate instead of an XLA stack trace.
 """
 from __future__ import annotations
 
@@ -41,8 +39,6 @@ from typing import Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro import compat
 
 LANES_AXIS = "lanes"
 
@@ -182,20 +178,3 @@ class MeshPlan:
         return jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
             params, specs)
-
-    # -- the collectives_emulated gate ---------------------------------------
-    def reraise_lowering(self, exc: Exception):
-        """Called when a program under this plan fails to lower/compile.
-        Old jax (``compat.collectives_emulated()``) cannot lower
-        gather/permute collectives in partial-manual regions — the 0.4.x
-        SPMD partitioner hard-aborts — so name the predicate instead of
-        leaking an XLA stack trace; on new jax re-raise untouched."""
-        if compat.collectives_emulated():
-            raise RuntimeError(
-                f"mesh plan {self.mesh} failed to lower on jax "
-                f"{jax.__version__}: this jax emulates collectives "
-                "(compat.collectives_emulated() — no jax.shard_map; the "
-                "0.4.x SPMD partitioner cannot lower gather/permute "
-                "collectives). Use a lanes-only plan (data=1, model=1) or "
-                "upgrade jax.") from exc
-        raise exc

@@ -501,9 +501,7 @@ class ServingEngine:
     ``run_many``'s lane axis over the plan's mesh (bit-exact — lanes are
     embarrassingly parallel) and the shared params over its within-lane
     axes (allclose); single-lane ``run`` has no lane axis to shard and
-    ignores it.  Lowering failures under a plan re-raise through
-    ``plan.reraise_lowering`` (the ``compat.collectives_emulated()``
-    gate)."""
+    ignores it."""
 
     def __init__(self, model, cfg: ServingConfig, prompts: Array,
                  plan: Optional[MeshPlan] = None):
@@ -584,10 +582,7 @@ class ServingEngine:
             lanes = self.plan.place_lanes(lanes)
             params = self.plan.place_params(params)
             with self.plan.mesh:
-                try:
-                    state, recs = jax.block_until_ready(fn(params, p, lanes))
-                except Exception as e:
-                    self.plan.reraise_lowering(e)
+                state, recs = jax.block_until_ready(fn(params, p, lanes))
         else:
             state, recs = jax.block_until_ready(fn(params, p, lanes))
         wall = time.perf_counter() - t0

@@ -625,7 +625,12 @@ def make_round_fn(loss_fn: Callable, optimizer, params_template, n_nodes: int, *
     idx = jnp.arange(n_nodes)
 
     def flatten_stack(tree) -> Array:
-        """pytree with leading node axis -> (N, D) fp32 matrix."""
+        """pytree with leading node axis -> (N, D) fp32 matrix.  The
+        barrier keeps XLA from fusing the producers (the whole backward
+        pass) into the concatenate: on TPU that fusion made the program's
+        generated code and compile time grow with D (~4x compile time at
+        58M parameters).  It is an identity, so no value changes."""
+        tree = jax.lax.optimization_barrier(tree)
         return jnp.concatenate([l.reshape(n_nodes, -1).astype(jnp.float32)
                                 for l in jax.tree.leaves(tree)], axis=1)
 
@@ -928,9 +933,9 @@ def run_campaign(loss_fn: Callable, params0, optimizer, data_fn: Callable,
     ``data_fn(node_idx, rnd)`` (or ``batched_data_fn(rnd)``) and ``eval_fn``
     must be jax-traceable.  ``fast_compile=True`` asks XLA for backend
     optimization level 0 — measured ~3x faster compiles with bit-identical
-    results on CPU; it silently falls back to a normal jit if this
-    jax/backend rejects the option.  Only use it for *tiny* models, where
-    campaigns are compile-bound: on real models the unfused code pays far
+    results on CPU; a backend that rejects the option raises.  Only use it
+    for *tiny* models, where campaigns are compile-bound: on real models
+    the unfused code pays far
     more in per-op memory traffic than it saves in compilation (measured
     ~4x slower end-to-end on the small-LM example).
     ``derailment.sweep`` picks this automatically by parameter count.
@@ -956,9 +961,7 @@ def run_campaign(loss_fn: Callable, params0, optimizer, data_fn: Callable,
     allclose only, see ``core/placement.py``), shared params over its
     within-lane ``data``/``model`` axes (allclose), and the one program
     runs under the plan's mesh with ``spmd_axis_name`` on the campaign
-    vmap.  Lowering failures under a plan re-raise through
-    ``plan.reraise_lowering`` — a clear error naming
-    ``compat.collectives_emulated()`` on old jax instead of an XLA abort.
+    vmap.
 
     Returns ``(final SwarmState, RoundRecord, final losses)`` with a leading
     run axis on every output leaf (RoundRecord leaves are (R, T, ...)).
@@ -976,21 +979,15 @@ def run_campaign(loss_fn: Callable, params0, optimizer, data_fn: Callable,
 
     def run_program():
         if fast_compile:
-            try:
-                return fn.lower(lanes).compile(
-                    compiler_options={
-                        "xla_backend_optimization_level": "0"})(lanes)
-            except Exception:
-                pass
+            return fn.lower(lanes).compile(
+                compiler_options={
+                    "xla_backend_optimization_level": "0"})(lanes)
         return fn(lanes)
 
     if plan is None:
         return run_program()
     with plan.mesh:
-        try:
-            return run_program()
-        except Exception as e:
-            plan.reraise_lowering(e)
+        return run_program()
 
 
 def make_campaign_program(loss_fn: Callable, params0, optimizer,
@@ -1441,12 +1438,33 @@ class Swarm(_SwarmBase):
         if not sched.any(axis=1).all():
             return False
         if self._batches_traceable is None:
+            # a data_fn that needs a concrete round raises a tracer error:
+            # int(rnd), numpy on it or a Python branch a JAXTypeError, a
+            # boolean mask built from it a JAXIndexError, a dict keyed by it
+            # Python's "unhashable type: '...Tracer'" TypeError.  Anything
+            # else is a real bug and propagates.
             try:
                 jax.eval_shape(self._traced_batch_fn(), jnp.asarray(0, jnp.int32))
                 self._batches_traceable = True
-            except Exception:
+            except (jax.errors.JAXTypeError, jax.errors.JAXIndexError):
+                self._batches_traceable = False
+            except TypeError as e:
+                if not ("unhashable type" in str(e) and "Tracer" in str(e)):
+                    raise
                 self._batches_traceable = False
         return self._batches_traceable
+
+    @property
+    def fused(self) -> bool:
+        """Whether the round resolved to the fused kernel path."""
+        return self._core.fused
+
+    def lower_step(self, rnd: int):
+        """The program :meth:`step` runs for round ``rnd``, lowered
+        (``jax.stages.Lowered``): compile it ahead of time or read its HLO.
+        Its compiled executable is what ``step`` then reuses."""
+        return self._round_fn.lower(self._state(), rnd,
+                                    self._stack_batches(rnd))
 
     # -- one round ----------------------------------------------------------------
     def step(self, rnd: int) -> dict:

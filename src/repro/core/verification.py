@@ -147,8 +147,8 @@ def min_p_check(gain_per_step: float, stake: float) -> float:
     Non-positive gain needs no auditing at all (rate 0)."""
     if gain_per_step <= 0.0:
         return 0.0
-    p = gain_per_step / max(stake, 1e-12)
-    while 0.0 < p < 1.0 and p * stake < gain_per_step:
+    p = gain_per_step / max(stake, 1e-12)   # may underflow to 0.0
+    while p < 1.0 and p * stake < gain_per_step:
         p = math.nextafter(p, 1.0)
     return min(1.0, p)
 

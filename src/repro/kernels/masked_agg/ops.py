@@ -21,9 +21,9 @@ Two implementations sit behind each twin:
   (N, N, D) difference tensor (~15x).  Gram d2 is *not* bit-equal to the
   broadcast d2 (cancellation at ~1e-6 relative), but krum's output is an
   argmin **selection** — equal except at exact score ties.
-- ``use_kernel=True`` (auto on TPU backends): the Pallas kernels from
-  ``kernel.py``, which additionally keep every D-sized intermediate in
-  VMEM tiles.  Tiled norm accumulation reorders float sums, so the kernel
+- ``use_kernel=True`` (the default on TPU backends — ``repro.kernels``
+  decides): the Pallas kernels from ``kernel.py``, which additionally
+  keep every D-sized intermediate in VMEM tiles.  Tiled norm accumulation reorders float sums, so the kernel
   path carries the same documented ~1e-5 relative divergence as the
   centralized centered_clip kernel.
 """
@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import aggregation
+from repro.kernels import interpret_mode, use_kernels
 from repro.kernels.masked_agg import kernel as _k
 from repro.kernels.qsgd_decode import ops as qdec
 
@@ -45,12 +46,6 @@ Array = jax.Array
 # (N·D·4 bytes) crosses this; below it the unfused path compiles faster and
 # the sort being replaced is already cheap.
 FUSED_MIN_BYTES = 4 << 20
-
-
-def _auto_kernel(use_kernel: Optional[bool]) -> bool:
-    if use_kernel is None:
-        return jax.default_backend() == "tpu"
-    return use_kernel
 
 
 def _as_f32_stack(updates) -> Array:
@@ -73,9 +68,10 @@ def masked_centered_clip_fused(updates, mask: Array, *,
                                clip_tau=None, iters: int = 3, v0=None,
                                use_kernel: Optional[bool] = None,
                                block_d: int = 2048,
-                               interpret: bool = False) -> Array:
+                               interpret: Optional[bool] = None) -> Array:
     x = _as_f32_stack(updates)
-    if _auto_kernel(use_kernel):
+    if use_kernels(use_kernel):
+        interpret = interpret_mode(interpret)
         v = (v0.astype(jnp.float32) if v0 is not None
              else _k.masked_median_fwd(x, mask, block_d=block_d,
                                        interpret=interpret))
@@ -95,10 +91,11 @@ def masked_centered_clip_fused(updates, mask: Array, *,
 def masked_krum_fused(updates, mask: Array, *, f: int = 1,
                       use_kernel: Optional[bool] = None,
                       block_d: int = 2048,
-                      interpret: bool = False) -> Array:
+                      interpret: Optional[bool] = None) -> Array:
     x = _as_f32_stack(updates)
-    if _auto_kernel(use_kernel):
-        d2 = _k.masked_krum_d2_fwd(x, block_d=block_d, interpret=interpret)
+    if use_kernels(use_kernel):
+        d2 = _k.masked_krum_d2_fwd(x, block_d=block_d,
+                                   interpret=interpret_mode(interpret))
     else:
         sq = jnp.sum(x * x, axis=1)
         d2 = sq[:, None] + sq[None, :] - 2.0 * (x @ x.T)
@@ -110,13 +107,13 @@ def masked_krum_fused(updates, mask: Array, *, f: int = 1,
 def masked_mean_fused(updates, mask: Array, *,
                       use_kernel: Optional[bool] = None,
                       block_d: int = 4096,
-                      interpret: bool = False) -> Array:
+                      interpret: Optional[bool] = None) -> Array:
     if isinstance(updates, qdec.QsgdPayload):
         k = jnp.maximum(jnp.sum(mask.astype(jnp.float32)), 1.0)
         acc = qdec.decode_accumulate(
             updates, mask.astype(jnp.float32),
-            use_kernel=_auto_kernel(use_kernel), block_d=block_d,
-            interpret=interpret)
+            use_kernel=use_kernels(use_kernel), block_d=block_d,
+            interpret=interpret_mode(interpret))
         return acc / k
     return aggregation.masked_mean(updates, mask)
 

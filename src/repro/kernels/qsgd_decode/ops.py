@@ -16,11 +16,13 @@ payloads into a stack the fused aggregators consume directly.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.compression import bucketed
+from repro.kernels import interpret_mode
 from repro.kernels.qsgd_decode.kernel import qsgd_decode_accumulate_fwd
 
 Array = jax.Array
@@ -29,7 +31,9 @@ Array = jax.Array
 @jax.tree_util.register_pytree_node_class
 class QsgdPayload:
     """codes (…, nb, B) int8 signed magnitudes, norms (…, nb, 1) f32 bucket
-    L2 norms; levels/size/bucket_size are static aux (vmap-/jit-safe)."""
+    L2 norms, nb padded to a multiple of 8 with zero buckets
+    (``compression.bucketed``); levels/size/bucket_size are static aux
+    (vmap-/jit-safe)."""
 
     def __init__(self, codes: Array, norms: Array, *, levels: int,
                  size: int, bucket_size: int):
@@ -71,8 +75,7 @@ def wire_encode(key, x: Array, *, levels: int = 16,
     if levels > 127:
         raise ValueError(f"int8 wire codes need levels <= 127, got {levels}")
     flat = x.reshape(-1).astype(jnp.float32)
-    pad = (-flat.size) % bucket_size
-    padded = jnp.pad(flat, (0, pad)).reshape(-1, bucket_size)
+    padded = bucketed(flat, bucket_size)
     norms = jnp.linalg.norm(padded, axis=1, keepdims=True)
     scaled = jnp.abs(padded) / jnp.maximum(norms, 1e-30) * levels
     lower = jnp.floor(scaled)
@@ -106,7 +109,7 @@ def wire_roundtrip(key, x: Array, *, levels: int = 16,
 
 def decode_accumulate(payload: QsgdPayload, weights: Array, *,
                       use_kernel: bool = False, block_d: int = 4096,
-                      interpret: bool = False) -> Array:
+                      interpret: Optional[bool] = None) -> Array:
     """Σᵢ wᵢ · decode(payloadᵢ) without a materialized decoded stack.
 
     ``payload`` is a node-batched QsgdPayload (codes (N, nb, B)); returns
@@ -120,7 +123,7 @@ def decode_accumulate(payload: QsgdPayload, weights: Array, *,
             payload.codes.reshape(n, nb * b),
             payload.norms.reshape(n, nb),
             weights, levels=payload.levels, bucket_size=b,
-            block_d=block_d, interpret=interpret)
+            block_d=block_d, interpret=interpret_mode(interpret))
     else:
         dec = (payload.codes.astype(jnp.float32)
                / payload.levels * payload.norms)            # (N, nb, B)

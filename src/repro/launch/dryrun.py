@@ -2,11 +2,13 @@
 every (architecture × input shape × mesh) — no hardware, no allocation.
 
 MUST be run as a module entry point:  PYTHONPATH=src python -m repro.launch.dryrun
-The first two lines create 512 placeholder host devices BEFORE any jax
-import (jax locks the device count at first init).
+The first lines create 512 placeholder host devices BEFORE any jax import
+(jax locks the device count at first init) and pin the CPU platform: this
+is a fake-device tool and never takes a chip.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse      # noqa: E402
 import json          # noqa: E402

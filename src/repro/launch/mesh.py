@@ -20,7 +20,7 @@ from typing import Dict, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 SINGLE_POD_SHAPE = (16, 16)
@@ -33,10 +33,17 @@ MULTI_POD_AXES = ("pod", "data", "model")
 CAMPAIGN_AXES = ("lanes", "data", "model")
 
 
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the launch layer places arrays
+    with sharding constraints that the compiler propagates, which an
+    Explicit-typed axis (``jax.make_mesh``'s default) refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = MULTI_POD_AXES if multi_pod else SINGLE_POD_AXES
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
@@ -51,7 +58,7 @@ def make_host_mesh(model: int = 1) -> Mesh:
         raise ValueError(
             f"model-axis factor {model} must be >= 1 and divide the "
             f"{n} available device(s)")
-    return jax.make_mesh((n // model, model), SINGLE_POD_AXES)
+    return _auto_mesh((n // model, model), SINGLE_POD_AXES)
 
 
 def make_campaign_mesh(lanes: Optional[int] = None, *, data: int = 1,
@@ -79,7 +86,8 @@ def make_campaign_mesh(lanes: Optional[int] = None, *, data: int = 1,
             f"campaign mesh ({lanes}, {data}, {model}) needs {need} "
             f"devices, have {len(devs)}")
     arr = np.asarray(devs[:need]).reshape(lanes, data, model)
-    return Mesh(arr, CAMPAIGN_AXES)
+    return Mesh(arr, CAMPAIGN_AXES,
+                axis_types=(AxisType.Auto,) * len(CAMPAIGN_AXES))
 
 
 def axis_sizes(mesh: Mesh) -> Dict[str, int]:

@@ -182,8 +182,7 @@ def analyze(compiled, hlo_text: str, *, num_chips: int,
     from repro.launch import hlo_cost
 
     cost = hlo_cost.analyze_hlo(hlo_text, total_devices=num_chips)
-    from repro import compat
-    xla = compat.cost_analysis_dict(compiled)
+    xla = dict(compiled.cost_analysis() or {})
     r = Roofline(
         flops_per_device=cost.flops,
         bytes_per_device=cost.bytes_accessed,

@@ -29,12 +29,32 @@ from repro.core.serving import (  # noqa: F401  (re-exported API)
 from repro.models.model import build_model
 
 
-def main(argv=None):
+def engine_for(model, prompts, *, slots: int, max_new: int):
+    """(ServingEngine, ServeLane) serving every prompt row once: all
+    requests arrive at step 0, each decodes ``max_new`` tokens, and the
+    scan horizon fits the queue through ``slots`` decode slots."""
     import numpy as np
 
+    n, prompt_len = prompts.shape
+    per_request = prompt_len + max_new
+    scfg = ServingConfig(
+        slots=slots, max_new=max_new,
+        steps=per_request + per_request * ((n + slots - 1) // slots))
+    lane = build_lane(
+        n_requests=n, prompt_lens=np.full(n, prompt_len, np.int32),
+        max_new=max_new, steps=scfg.steps, n_nodes=8,
+        balances=[float(n)] * 4, fee=1.0, load=1.0)
+    return ServingEngine(model, scfg, prompts), lane
+
+
+def main(argv=None):
     import argparse
-    ap = argparse.ArgumentParser(description="CPU serving driver")
+    ap = argparse.ArgumentParser(description="serving driver")
     ap.add_argument("--arch", default="protocol-125m")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny same-family variant (default); "
+                         "--no-reduced serves the published width")
     ap.add_argument("--driver", default="scan",
                     choices=("scan", "loop", "engine"))
     ap.add_argument("--batch", type=int, default=4,
@@ -45,28 +65,20 @@ def main(argv=None):
                     help="engine: decode slot-pool size")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     prompts = jax.random.randint(
         jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0, cfg.vocab_size)
 
     if args.driver == "engine":
-        scfg = ServingConfig(
-            slots=args.slots, max_new=args.max_new,
-            steps=args.prompt_len + args.max_new
-            + (args.prompt_len + args.max_new)
-            * ((args.batch + args.slots - 1) // args.slots))
-        lane = build_lane(
-            n_requests=args.batch,
-            prompt_lens=np.full(args.batch, args.prompt_len, np.int32),
-            max_new=args.max_new,
-            steps=scfg.steps, n_nodes=8, balances=[float(args.batch)] * 4,
-            fee=1.0, load=1.0)
-        engine = ServingEngine(model, scfg, prompts)
+        engine, lane = engine_for(model, prompts, slots=args.slots,
+                                  max_new=args.max_new)
         engine.run(params, lane)                     # warm the program
         res = engine.run(params, lane)
-        print(f"arch={cfg.name} engine slots={scfg.slots} "
+        print(f"arch={cfg.name} engine slots={args.slots} "
               f"requests={args.batch} served={int(res.done.sum())} "
               f"tokens={res.tokens_served} ({res.tok_per_s:.1f} tok/s, "
               f"availability {res.availability:.2f})")
@@ -82,4 +94,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

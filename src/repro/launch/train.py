@@ -1,4 +1,4 @@
-"""Distributed train-step builder + a runnable CPU trainer.
+"""Distributed train-step builder + a runnable single-host trainer.
 
 Two regimes, selected by the mesh and ``TrainOptions.pod_sync``:
 
@@ -14,8 +14,8 @@ Two regimes, selected by the mesh and ``TrainOptions.pod_sync``:
   CenteredClip.  The dry-run HLO shows the wire dtype/schedule directly.
 
 Also provides grad-accumulation microbatching (perf knob for the memory
-roofline term) and the ``python -m repro.launch.train`` CPU driver used by
-the examples.
+roofline term) and the ``python -m repro.launch.train`` driver used by the
+examples.
 """
 from __future__ import annotations
 
@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.hierarchical import get_pod_sync
 from repro.launch import mesh as mesh_lib
@@ -242,37 +241,31 @@ def make_train_step(model: Model, optimizer, mesh: Mesh,
 
     sync = get_pod_sync(opts.pod_sync, **opts.sync_kwargs)
     # Inside the manual-pod region the batch's pod dim is already local, so
-    # activation constraints must not name "pod": old jax's partitioner
-    # hard-aborts (IsManualSubgroup) on constraints over manual axes.
+    # activation constraints must not name the manual "pod" axis.
     inner_batch_axes = tuple(a for a in mesh_lib.batch_axes(mesh)
                              if a != "pod")
 
-    def per_pod(state, batch, pod_ids):
-        # batch is this pod's local shard; data/model axes remain automatic.
-        # pod_ids is an arange sharded over "pod", so pod_ids[0] is this
-        # pod's index — the data-derived identity compat's emulated
-        # collectives need where axis_index/all_gather can't lower (old jax
-        # partial-manual mode).
+    def per_pod(state, batch):
+        # batch is this pod's local shard; data/model axes remain automatic
         with shrules.activation_sharding(
                 inner_batch_axes,
                 model_axis_size=mesh_lib.axis_sizes(mesh).get("model", 1)):
             loss, grads = grad_fn(state.params, batch)
-        grads = sync(grads, "pod", pod_index=pod_ids[0])
+        grads = sync(grads, "pod")
         loss = jax.lax.pmean(loss, "pod")
         return apply_update(state, loss, grads)
 
     def step(state, batch):
         batch_specs = _pod_batch_specs(batch)
         state_specs = jax.tree.map(lambda _: P(), state)
-        pod_ids = jnp.arange(mesh.shape["pod"], dtype=jnp.int32)
-        return compat.shard_map(
+        return jax.shard_map(
             per_pod,
             mesh=mesh,
-            in_specs=(state_specs, batch_specs, P("pod")),
+            in_specs=(state_specs, batch_specs),
             out_specs=(state_specs, {"loss": P(), "grad_norm": P()}),
-            axis_names={"pod"},
-            check=False,
-        )(state, batch, pod_ids)
+            axis_names=frozenset({"pod"}),
+            check_vma=False,
+        )(state, batch)
 
     return step
 
@@ -312,7 +305,7 @@ def serve_shardings(model: Model, shape: ShapeConfig, mesh: Mesh):
     return ns(tok_spec), ns(cache_spec)
 
 
-# -- CPU driver -----------------------------------------------------------------
+# -- driver ---------------------------------------------------------------------
 def main(argv=None):
     import argparse
 
@@ -321,14 +314,17 @@ def main(argv=None):
     from repro.models.model import build_model
     from repro.optim.optimizer import AdamW, cosine_schedule
 
-    ap = argparse.ArgumentParser(description="CPU trainer (reduced configs)")
+    ap = argparse.ArgumentParser(description="trainer")
     ap.add_argument("--arch", default="protocol-125m")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the tiny same-family variant (default); "
+                         "--no-reduced trains the published width")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
@@ -359,4 +355,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

@@ -53,11 +53,11 @@ def attention(
 
     if window is not None:
         if use_pallas and sq == skv:
-            import jax as _jax
+            from repro.kernels import interpret_mode
             from repro.kernels.swa_attention.ops import swa_attention
             return swa_attention(
                 q, k, v, window=window, block_q=min(q_block, 128),
-                interpret=_jax.default_backend() != "tpu")
+                interpret=interpret_mode())
         return _swa(qg, k, v, window=window, q_block=q_block, scale=scale)
 
     kv_block = min(kv_block, skv)

@@ -115,7 +115,12 @@ def chunked_softmax_xent(
         hc, yc, mc = args
         logits = jnp.einsum("bsd,dv->bsv", hc.astype(jnp.float32), unembed.astype(jnp.float32))
         logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, yc[..., None], axis=-1)[..., 0]
+        # the gold logit as a masked sum over the vocab, not a gather: equal
+        # for finite logits, and it partitions like logsumexp where the
+        # vocab is model-sharded (a gather there trips an XLA SPMD check
+        # inside the multi-pod step's manual "pod" region; docs/architecture.md)
+        vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+        gold = jnp.sum(jnp.where(vocab == yc[..., None], logits, 0.0), axis=-1)
         return jnp.sum((logz - gold) * mc)
 
     chunk_loss = jax.checkpoint(chunk_loss)

@@ -138,11 +138,11 @@ def mamba_block_apply(params, cfg, x: Array, *, chunk: int = 256):
     a = -jnp.exp(params["a_log"])
     xh = xin.reshape(*xin.shape[:2], nheads, cfg.ssm_head_dim)
     if cfg.use_pallas_kernels:
-        import jax as _jax
+        from repro.kernels import interpret_mode
         from repro.kernels.mamba2_scan.ops import ssd_chunked_pallas
         y, _ = ssd_chunked_pallas(xh, dt, a, b, c, params["d_skip"],
                                   chunk=chunk,
-                                  interpret=_jax.default_backend() != "tpu")
+                                  interpret=interpret_mode())
     else:
         y, _ = ssd_chunked(xh, dt, a, b, c, params["d_skip"], chunk=chunk)
     y = y.reshape(*x.shape[:2], d_in) * jax.nn.silu(z)

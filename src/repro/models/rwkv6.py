@@ -151,11 +151,11 @@ def time_mix(params, cfg, x: Array, *, chunk: int = 256):
     to_h = lambda t: t.reshape(b, s, nheads, hd)
     u = params["u_bonus"].reshape(nheads, hd)
     if cfg.use_pallas_kernels:
-        import jax as _jax
+        from repro.kernels import interpret_mode
         from repro.kernels.rwkv6_wkv.ops import wkv_chunked_pallas
         y, _ = wkv_chunked_pallas(
             to_h(r), to_h(k), to_h(v), to_h(w.astype(x.dtype)), u,
-            chunk=chunk, interpret=_jax.default_backend() != "tpu")
+            chunk=chunk, interpret=interpret_mode())
     else:
         y, _ = wkv_chunked(to_h(r), to_h(k), to_h(v), to_h(w.astype(x.dtype)),
                            u, chunk=chunk)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 Array = jax.Array
 
 
@@ -43,7 +41,7 @@ def spmd_pipeline(stage_fn: Callable, stage_params, xs: Array, *, axis: str = "p
     xs: (M, mb, ...) microbatches (same on every stage).
     Returns ys: (M, mb, ...) — valid on the LAST stage, zeros elsewhere.
     """
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     m = xs.shape[0]
     ticks = num_ticks(m, p)
@@ -63,8 +61,8 @@ def spmd_pipeline(stage_fn: Callable, stage_params, xs: Array, *, axis: str = "p
         recv = jax.lax.ppermute(out, axis, perm)
         return (recv, ys), None
 
-    recv0 = compat.pvary(jnp.zeros_like(xs[0]), (axis,))
-    ys0 = compat.pvary(jnp.zeros_like(xs), (axis,))
+    recv0 = jax.lax.pvary(jnp.zeros_like(xs[0]), (axis,))
+    ys0 = jax.lax.pvary(jnp.zeros_like(xs), (axis,))
     (recv, ys), _ = jax.lax.scan(tick_fn, (recv0, ys0), jnp.arange(ticks))
     # broadcast final outputs from the last stage to everyone
     mask = (stage == p - 1).astype(ys.dtype)
@@ -87,11 +85,14 @@ def make_pipeline_apply(layer_fn: Callable, mesh: Mesh, *, axis: str = "pipe"):
     def apply(stacked_params, xs):
         fn = functools.partial(spmd_pipeline, stage_fn, axis=axis)
         spec_params = jax.tree.map(lambda _: P(axis), stacked_params)
-        return compat.shard_map(
-            fn, mesh=mesh,
-            in_specs=(spec_params, P()),
-            out_specs=P(),
-        )(stacked_params, xs)
+        # the mesh context lets the caller's single-device arrays enter
+        # the stage-sharded program
+        with jax.set_mesh(mesh):
+            return jax.shard_map(
+                fn, mesh=mesh,
+                in_specs=(spec_params, P()),
+                out_specs=P(),
+            )(stacked_params, xs)
 
     return apply
 

@@ -35,8 +35,7 @@ def _audit(fn, *args, declared_axes=frozenset(), **make_jaxpr_kwargs):
 
 # =============================== JX rules =====================================
 def test_jx001_fires_on_f64():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         vs = _audit(lambda x: x.astype("float64") * 2.0,
                     jnp.zeros(4, jnp.float32))
     assert "JX001" in _codes(vs)
@@ -75,12 +74,11 @@ def test_jx003_quiet_without_callbacks():
 
 
 def test_jx004_fires_on_dynamic_shape():
-    jax.config.update("jax_dynamic_shapes", True)
-    try:
-        closed = jax.make_jaxpr(lambda x: x + x,
-                                abstracted_axes=("n",))(jnp.arange(4.0))
-    finally:
-        jax.config.update("jax_dynamic_shapes", False)
+    # a symbolic dimension (shape polymorphism) is the non-static shape
+    # the installed jax can still put into a jaxpr
+    n, = jax.export.symbolic_shape("n")
+    closed = jax.make_jaxpr(lambda x: x + x)(
+        jax.ShapeDtypeStruct((n,), jnp.float32))
     vs = jaxpr_audit._audit_unit("prog", TracedUnit("t", closed))
     assert "JX004" in _codes(vs)
 
@@ -195,6 +193,37 @@ def test_pk004_quiet_on_lane_multiple_tiling():
     call = _call((4,), [_Spec((128,), lambda i: (i,))], [((512,), 4)],
                  [_Spec((128,), lambda i: (i,))], [((512,), 4)])
     assert pallas_check._check_call(call) == []
+
+
+# the qsgd decode-accumulate norms at N=10, D=2^20, 512-element buckets
+_N, _D, _BUCKET, _BLOCK = 10, 1 << 20, 512, 4096
+
+
+def test_pk004_fires_on_old_sub_lane_norms_block():
+    """The norms BlockSpec the TPU compiler refused: 8 bucket norms per
+    step over a trailing dim of D/512 — neither lane-aligned nor full."""
+    call = _call((_D // _BLOCK,),
+                 [_Spec((_N, _BLOCK // _BUCKET), lambda j: (0, j))],
+                 [((_N, _D // _BUCKET), 4)],
+                 [_Spec((1, _BLOCK), lambda j: (0, j))], [((1, _D), 4)])
+    assert "PK004" in _codes(pallas_check._check_call(call))
+
+
+def test_pk004_quiet_on_grouped_norms_block():
+    """The repaired layout: norms regrouped to (nb/8, N, 8), so both
+    trailing block dims equal the array's."""
+    groups = _D // _BUCKET // 8
+    call = _call((_D // _BLOCK,),
+                 [_Spec((_BLOCK // _BUCKET // 8, _N, 8), lambda j: (j, 0, 0))],
+                 [((groups, _N, 8), 4)],
+                 [_Spec((1, _BLOCK), lambda j: (0, j))], [((1, _D), 4)])
+    assert pallas_check._check_call(call) == []
+
+
+def test_pk004_fires_on_sub_sublane_second_minor():
+    call = _call((4,), [_Spec((4, 128), lambda i: (i, 0))], [((16, 128), 4)],
+                 [_Spec((4, 128), lambda i: (i, 0))], [((16, 128), 4)])
+    assert "PK004" in _codes(pallas_check._check_call(call))
 
 
 # =============================== PL rules =====================================

@@ -6,12 +6,15 @@ The conformance suite runs in a subprocess with its own XLA_FLAGS
 (``--xla_force_host_platform_device_count=8``, the test_launch.py pattern)
 because the flag must be set before jax imports.  Inside it:
 
-- a lane-sharded ``derailment.sweep`` is **bit-equal** to the single-device
-  sweep — final params, the whole SwarmState, and every ``RoundRecord``
-  counter (lanes are embarrassingly parallel, so sharding the run axis
-  must not change a single training bit); the one exception is the final
-  *eval* scalar, where XLA may fuse the eval matmul differently under a
-  mesh — pinned 1-ULP allclose instead;
+- a lane-sharded ``run_campaign`` (two lanes per device) is **bit-equal**
+  both to the unsharded engine run on each device's block of lanes and to
+  one unsharded program over all lanes — final params, the whole
+  SwarmState, and every ``RoundRecord`` field (lanes are embarrassingly
+  parallel, so sharding the run axis must not change a single training
+  bit); the one exception is the final *eval* scalar, where XLA may fuse
+  the eval matmul differently under a mesh — pinned 1-ULP allclose
+  instead;
+- a lane-sharded ``derailment.sweep`` matches the single-device sweep;
 - a param-sharded (model-axis) plan is **allclose** (resharding reorders
   float reductions);
 - the campaign program does **not recompile** under a mesh (second call,
@@ -76,24 +79,37 @@ def assert_tree_bitequal(a, b, what):
 nodes = [NodeSpec("h%d" % i) for i in range(4)] + [
     NodeSpec("adv", byzantine="sign_flip", byzantine_scale=20.0)]
 lanes = stack_lanes([lane_for_nodes(nodes, SwarmConfig(seed=s))
-                     for s in range(8)])
-ref = run_campaign(loss_fn, params0, opt, data_fn, lanes, rounds=4,
-                   aggregator="centered_clip", eval_fn=eval_fn)
-plan = MeshPlan.for_lanes(8)
+                     for s in range(16)])
+def campaign(lanes, plan=None):
+    return run_campaign(loss_fn, params0, opt, data_fn, lanes, rounds=4,
+                        aggregator="centered_clip", eval_fn=eval_fn,
+                        plan=plan)
+def check_bitequal(out, ref, what):
+    st_o, rec_o, fin_o = out
+    st_r, rec_r, fin_r = ref
+    for f in rec_r._fields:
+        assert_tree_bitequal(getattr(rec_o, f), getattr(rec_r, f),
+                             what + ": RoundRecord." + f)
+    assert_tree_bitequal(st_o.params, st_r.params, what + ": state.params")
+    assert_tree_bitequal(st_o, st_r, what + ": SwarmState")
+    # the final eval matmul is the one op XLA may fuse differently under
+    # a mesh: the training state is bit-exact, the eval is 1-ULP close
+    assert np.allclose(np.asarray(fin_o), np.asarray(fin_r), rtol=1e-6), \
+        (what, fin_o, fin_r)
+plan = MeshPlan.for_lanes(16)
 assert plan.lane_devices == 8, plan.mesh
-out = run_campaign(loss_fn, params0, opt, data_fn, lanes, rounds=4,
-                   aggregator="centered_clip", eval_fn=eval_fn, plan=plan)
-st_r, rec_r, fin_r = ref
-st_o, rec_o, fin_o = out
-for f in rec_r._fields:
-    assert_tree_bitequal(getattr(rec_o, f), getattr(rec_r, f),
-                         "RoundRecord." + f)
-assert_tree_bitequal(st_o.params, st_r.params, "state.params")
-assert_tree_bitequal(st_o, st_r, "SwarmState")
-# the final eval matmul is the one op XLA may fuse differently under a
-# mesh: the training state is bit-exact, the eval scalar is 1-ULP close
-assert np.allclose(np.asarray(fin_o), np.asarray(fin_r), rtol=1e-6), \
-    (fin_o, fin_r)
+out = campaign(lanes, plan)                 # two lanes per device
+# against the unsharded engine on each device's block of lanes — the
+# program every device runs — and against one program over all 16 lanes.
+# (A block of ONE lane is not that program on the CPU: XLA drops the
+# size-1 lane dim and the forward dot becomes matrix-vector, whose sums
+# round differently from the matrix-matrix dot of a wider lane batch.)
+blocks = [campaign(jax.tree.map(lambda x: x[i:i + 2], lanes))
+          for i in range(0, 16, 2)]
+check_bitequal(out, jax.tree.map(
+    lambda *xs: np.concatenate([np.asarray(x) for x in xs]), *blocks),
+    "per-device blocks")
+check_bitequal(out, campaign(lanes), "one 16-lane program")
 print("RUN_CAMPAIGN_BITEXACT_OK")
 
 # -- 2) derailment.sweep: lane-sharded phase diagram bit-equal -------------------

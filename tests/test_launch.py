@@ -79,12 +79,11 @@ def test_pod_sync_registry_and_identity():
     for name, fn in POD_SYNC.items():
         if name == "gossip":
             continue                      # ring needs >= 2 members
-        from repro import compat
-        out = jax.jit(compat.shard_map(
+        out = jax.jit(jax.shard_map(
             lambda g: fn(g, "pod"), mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(),),
             out_specs=jax.sharding.PartitionSpec(),
-            check=False))(grads)
+            check_vma=False))(grads)
         for k in grads:
             np.testing.assert_allclose(np.asarray(out[k]),
                                        np.asarray(grads[k]),
@@ -276,8 +275,7 @@ def test_hlo_cost_multiplies_loop_trip_count():
     expected = 10 * 2 * 32 * 64 * 64
     assert cost.flops == pytest.approx(expected, rel=0.05)
     # the raw XLA analysis would report ~1/10th of this
-    from repro import compat
-    xla = compat.cost_analysis_dict(compiled)
+    xla = dict(compiled.cost_analysis() or {})
     if xla.get("flops"):
         assert cost.flops > 5 * float(xla["flops"])
 
@@ -322,13 +320,6 @@ def test_dryrun_subprocess_single_pod(tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="old-jax (0.4.x) SPMD partitioner aborts on grad-of-scan inside a "
-           "partial-manual shard_map (IsManualSubgroup check); the layer "
-           "stack is a differentiated scan, so non-dense pod sync needs the "
-           "new-API stack.  The sync collectives themselves are covered by "
-           "test_pod_sync_partial_manual_subprocess.")
 def test_dryrun_subprocess_multi_pod_qsgd(tmp_path):
     """512-chip multi-pod with int8-on-the-wire pod sync lowers + compiles."""
     out = subprocess.run(
@@ -350,18 +341,16 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core.hierarchical import POD_SYNC
 mesh = jax.make_mesh((4, 2), ("pod", "data"))
 grads = {"w": jnp.arange(32.0).reshape(4, 8), "b": jnp.ones((4, 2))}
-pod_ids = jnp.arange(4, dtype=jnp.int32)
 for name in ("dense", "qsgd", "median", "centered_clip", "gossip"):
     fn = POD_SYNC[name]
-    out = jax.jit(compat.shard_map(
-        lambda g, i: fn(g, "pod", pod_index=i[0]), mesh=mesh,
-        in_specs=(jax.tree.map(lambda _: P("pod"), grads), P("pod")),
+    out = jax.jit(jax.shard_map(
+        lambda g: fn(g, "pod"), mesh=mesh,
+        in_specs=(jax.tree.map(lambda _: P("pod"), grads),),
         out_specs=jax.tree.map(lambda _: P("pod"), grads),
-        axis_names={"pod"}, check=False))(grads, pod_ids)
+        axis_names=frozenset({"pod"}), check_vma=False))(grads)
     for k in grads:
         mean = np.asarray(jnp.mean(grads[k], 0))
         got = np.asarray(out[k])
@@ -382,8 +371,8 @@ print("POD_SYNC_PM_OK")
 @pytest.mark.slow
 def test_pod_sync_partial_manual_subprocess():
     """Every pod-sync mode lowers and runs inside a *partial-manual*
-    shard_map (the multi-pod train-step context) — on old jax this exercises
-    compat's psum-emulated all_gather/ppermute with data-derived pod ids."""
+    shard_map (the multi-pod train-step context): all_gather and ppermute
+    over the manual pod axis while data stays automatic."""
     out = subprocess.run(
         [sys.executable, "-c", POD_SYNC_PARTIAL_MANUAL_SCRIPT],
         capture_output=True, text=True, timeout=300,

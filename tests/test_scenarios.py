@@ -211,6 +211,43 @@ def test_scanned_run_is_one_program_and_matches_step_loop():
         assert scan_fn._cache_size() == 1
 
 
+@pytest.mark.parametrize("needs", ["int", "bool_index", "dict_key", "bug"])
+def test_run_steps_when_data_fn_needs_a_concrete_round(needs):
+    """A data_fn that cannot be traced in the round (it needs the round as
+    a Python value) makes run() fall back to the step loop, with the same
+    history; any other error from it propagates."""
+    loss_fn, params0, data_fn, _ = tiny_quadratic_problem(8)
+    table = {r: r for r in range(6)}
+
+    def concrete_data_fn(i, rnd):
+        if needs == "int":
+            rnd = int(rnd)
+        elif needs == "bool_index":
+            rnd = jnp.sum(jnp.arange(6)[jnp.arange(6) < rnd])
+        elif needs == "dict_key":
+            rnd = table[rnd]
+        else:
+            raise ValueError("a bug in the data_fn")
+        return data_fn(i, rnd)
+
+    nodes = [NodeSpec(f"h{i}") for i in range(4)]
+    cfg = SwarmConfig(aggregator="mean")
+    swarm = make_swarm(loss_fn, params0, SGD(lr=0.1, momentum=0.0), nodes,
+                       cfg, concrete_data_fn)
+    if needs == "bug":
+        with pytest.raises(ValueError, match="a bug"):
+            swarm.run(6)
+        return
+    swarm.run(6)
+    assert not swarm._scan_cache                    # never took the scan
+    stepped = make_swarm(loss_fn, params0, SGD(lr=0.1, momentum=0.0), nodes,
+                         cfg, concrete_data_fn)
+    for r in range(6):
+        stepped.step(r)
+    assert [r["agg_norm"] for r in swarm.history] == \
+        [r["agg_norm"] for r in stepped.history]
+
+
 def test_make_swarm_rejects_batched_data_fn_on_sequential():
     loss_fn, params0, data_fn, _ = tiny_quadratic_problem(8)
     with pytest.raises(ValueError, match="batched_data_fn"):

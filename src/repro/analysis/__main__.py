@@ -4,7 +4,7 @@ Runs all three analyzers:
 
 1. ``jaxpr_audit`` over the five engine programs (round fused/unfused,
    campaign, sweep, serve scan) — rules JX001-JX007,
-2. ``pallas_check`` over every registered kernel probe — rules PK001-PK004,
+2. ``pallas_check`` over every registered kernel probe — rules PK001-PK005,
 3. ``tracer_lint`` over ``src/`` — rules PL001-PL005,
 
 applies the checked-in baseline (``baseline.json`` next to this package;

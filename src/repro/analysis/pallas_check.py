@@ -27,6 +27,8 @@ with python ints.
   ``masked_agg._pad_lanes`` exists to guarantee.  Sub-lane arrays are in
   scope: a per-bucket norms block of 8 over a trailing dim of D/512 is
   refused by the TPU compiler, and by this rule.
+- **PK005** every call passes ``name=``: the name is what the profiler's
+  trace shows for the kernel (an unnamed call reads ``_unknown_.N``).
 """
 from __future__ import annotations
 
@@ -59,6 +61,7 @@ class CapturedCall:
     out_shapes: List[Tuple[Tuple[int, ...], int]]
     scratch_bytes: int
     num_scalar_prefetch: int
+    name: Optional[str] = None     # the call's ``name=``
 
     def label(self, kind: str, i: int) -> str:
         return f"{self.kernel}[{self.index}].{kind}{i}"
@@ -91,7 +94,8 @@ def capture_pallas_calls(records: List[CapturedCall], kernel: str):
     counter = itertools.count()
 
     def fake(kern, *pargs, out_shape=None, grid_spec=None, grid=None,
-             in_specs=None, out_specs=None, scratch_shapes=(), **kw):
+             in_specs=None, out_specs=None, scratch_shapes=(), name=None,
+             **kw):
         if out_shape is None and pargs:
             out_shape, pargs = pargs[0], pargs[1:]
         nsp = 0
@@ -117,7 +121,7 @@ def capture_pallas_calls(records: List[CapturedCall], kernel: str):
                 out_shapes=[(tuple(o.shape), jnp.dtype(o.dtype).itemsize)
                             for o in outs],
                 scratch_bytes=_scratch_bytes(scratch_shapes),
-                num_scalar_prefetch=nsp))
+                num_scalar_prefetch=nsp, name=name))
             zeros = [jnp.zeros(o.shape, o.dtype) for o in outs]
             if isinstance(out_shape, (tuple, list)):
                 return type(out_shape)(zeros)
@@ -148,6 +152,10 @@ def _eval_map(spec, point: Sequence[int], nsp: int) -> Optional[Tuple[int, ...]]
 def _check_call(call: CapturedCall,
                 budget: int = VMEM_BYTES) -> List[Violation]:
     out: List[Violation] = []
+    if not call.name:
+        out.append(Violation(
+            "PK005", f"{call.kernel}[{call.index}]",
+            "pallas_call without name=: the trace shows it as _unknown_"))
     vmem = _vmem_bytes(call)
     if vmem > budget:
         out.append(Violation(

@@ -37,6 +37,7 @@ RULES: Dict[str, str] = {
     "PK002": "kernel tile reads/writes past the padded array bounds",
     "PK003": "kernel VMEM tile footprint exceeds its budget",
     "PK004": "block violates Mosaic's (8, 128) tiling rule on its last two dims",
+    "PK005": "pallas_call passes no name= (the trace shows _unknown_)",
     # -- tracer_lint ----------------------------------------------------------
     "PL000": "stale baseline entry (key no longer fires)",
     "PL001": "python if/while on a traced expression inside a traced fn",

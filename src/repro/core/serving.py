@@ -227,6 +227,7 @@ class ServeState(NamedTuple):
     done: Array           # (R,) bool — all max_new tokens delivered
     balances: Array       # (H,) f32 — live credential balances
     out_tokens: Array     # (R, max_new) int32 — delivered tokens
+    admit_step: Array     # (R,) int32 — the step that admitted it; -1 never
 
 
 class ServeRecord(NamedTuple):
@@ -293,104 +294,117 @@ def make_serve_step(model, cfg: ServingConfig, prompt_shape: Tuple[int, int],
                         in_axes=(None, 0, 0))(params, toks, caches)
 
     def step(params, prompts: Array, lane: ServeLane, state: ServeState, t):
-        # -- availability: who holds the model right now ------------------------
-        online = ~((lane.node_down_from <= t) & (t < lane.node_down_until))
-        if has_custody:
-            covered = jnp.any(lane.custody & online[:, None], axis=0)
-            coverage = jnp.mean(covered.astype(jnp.float32))
-            live = jnp.all(covered)
-        else:
-            coverage = jnp.ones((), jnp.float32)
-            live = jnp.ones((), bool)
+        # each stage under its scope (``jax.named_scope``, op metadata only):
+        # ``repro.analysis.stages`` joins a profiler's device time to them
+        with jax.named_scope("serve.admit"):
+            # -- availability: who holds the model right now ----------------
+            online = ~((lane.node_down_from <= t) & (t < lane.node_down_until))
+            if has_custody:
+                covered = jnp.any(lane.custody & online[:, None], axis=0)
+                coverage = jnp.mean(covered.astype(jnp.float32))
+                live = jnp.all(covered)
+            else:
+                coverage = jnp.ones((), jnp.float32)
+                live = jnp.ones((), bool)
 
-        # -- admission: queued requests fill free slots in arrival order --------
-        occ = state.slot_req < n_req
-        waiting = (~state.admitted) & (lane.arrivals <= t)
-        # funding is strict (balance - fee > min_shares, the can_infer
-        # boundary) and accounts for waiting same-holder siblings: the
-        # k-th waiting request of a holder (by request index) must afford
-        # k+1 fees.  Any same-step admitted subset of a holder then needs
-        # at least |subset| fees — a burst can never drive a balance past
-        # the boundary, whatever order admission picks.  The index-prefix
-        # rule is deliberately deterministic: when a holder cannot fund
-        # every waiting sibling, the LOWEST-index ones stay fundable (a
-        # documented tie-break, not a fairness guarantee).
-        idx = jnp.arange(n_req)
-        prior_same = jnp.sum((lane.holders[:, None] == lane.holders[None, :])
-                             & waiting[None, :]
-                             & (idx[:, None] > idx[None, :]), axis=1)
-        funded = (state.balances[lane.holders]
-                  - (prior_same + 1).astype(jnp.float32) * lane.fee
-                  > cfg.min_shares)
-        cand = waiting & funded & live
-        # FIFO: priority by (arrival step, request index) — a request that
-        # has waited longer is admitted first, whatever its index (ties
-        # and the monotone-arrival builders reduce to request order)
-        fifo = lane.arrivals * n_req + idx                     # (R,)
-        rank = jnp.sum(cand[None, :]
-                       & (fifo[None, :] < fifo[:, None]), axis=1)
-        admit = cand & (rank < jnp.sum(~occ))
-        free_first = jnp.argsort(occ)            # free slots, in slot order
-        slot_of = free_first[jnp.clip(rank, 0, slots - 1)]
-        scatter_to = jnp.where(admit, slot_of, slots)
-        upd = jnp.full((slots,), -1, jnp.int32).at[scatter_to].set(
-            jnp.arange(n_req, dtype=jnp.int32), mode="drop")
-        newly = upd >= 0
-        slot_req = jnp.where(newly, upd, state.slot_req)
-        slot_t = jnp.where(newly, 0, state.slot_t)
-        caches = jax.tree.map(
-            lambda init, c: jnp.where(
-                newly.reshape((slots,) + (1,) * init.ndim),
-                init[None], c),
-            template, state.caches)
-        balances = state.balances.at[
-            jnp.where(admit, lane.holders, lane.balances.shape[0])
-        ].add(-lane.fee, mode="drop")
-        admitted = state.admitted | admit
-        occ = slot_req < n_req
+            # -- admission: queued requests fill free slots in arrival order 
+            occ = state.slot_req < n_req
+            waiting = (~state.admitted) & (lane.arrivals <= t)
+            # funding is strict (balance - fee > min_shares, the can_infer
+            # boundary) and accounts for waiting same-holder siblings: the
+            # k-th waiting request of a holder (by request index) must afford
+            # k+1 fees.  Any same-step admitted subset of a holder then needs
+            # at least |subset| fees — a burst can never drive a balance past
+            # the boundary, whatever order admission picks.  The index-prefix
+            # rule is deliberately deterministic: when a holder cannot fund
+            # every waiting sibling, the LOWEST-index ones stay fundable (a
+            # documented tie-break, not a fairness guarantee).
+            idx = jnp.arange(n_req)
+            prior_same = jnp.sum(
+                (lane.holders[:, None] == lane.holders[None, :])
+                & waiting[None, :] & (idx[:, None] > idx[None, :]), axis=1)
+            funded = (state.balances[lane.holders]
+                      - (prior_same + 1).astype(jnp.float32) * lane.fee
+                      > cfg.min_shares)
+            cand = waiting & funded & live
+            # FIFO: priority by (arrival step, request index) — a request
+            # that has waited longer is admitted first, whatever its index
+            # (ties and the monotone-arrival builders reduce to request
+            # order)
+            fifo = lane.arrivals * n_req + idx                     # (R,)
+            rank = jnp.sum(cand[None, :]
+                           & (fifo[None, :] < fifo[:, None]), axis=1)
+            admit = cand & (rank < jnp.sum(~occ))
+            free_first = jnp.argsort(occ)        # free slots, in slot order
+            slot_of = free_first[jnp.clip(rank, 0, slots - 1)]
+            scatter_to = jnp.where(admit, slot_of, slots)
+            upd = jnp.full((slots,), -1, jnp.int32).at[scatter_to].set(
+                jnp.arange(n_req, dtype=jnp.int32), mode="drop")
+            newly = upd >= 0
+            slot_req = jnp.where(newly, upd, state.slot_req)
+            slot_t = jnp.where(newly, 0, state.slot_t)
+            caches = jax.tree.map(
+                lambda init, c: jnp.where(
+                    newly.reshape((slots,) + (1,) * init.ndim),
+                    init[None], c),
+                template, state.caches)
+            balances = state.balances.at[
+                jnp.where(admit, lane.holders, lane.balances.shape[0])
+            ].add(-lane.fee, mode="drop")
+            admitted = state.admitted | admit
+            admit_step = jnp.where(admit, t, state.admit_step)
+            occ = slot_req < n_req
 
-        # -- decode: every slot advances one token ------------------------------
-        req = jnp.minimum(slot_req, n_req - 1)
-        plen = lane.prompt_lens[req]
-        tok_in = jnp.where(slot_t < plen,
-                           prompts[req, jnp.clip(slot_t, 0, p_max - 1)],
-                           state.last_tok)
-        logits, new_caches = decode_all(params, tok_in[:, None, None], caches)
-        next_tok = jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope("serve.decode"):
+            # -- decode: every slot advances one token ----------------------
+            req = jnp.minimum(slot_req, n_req - 1)
+            plen = lane.prompt_lens[req]
+            tok_in = jnp.where(slot_t < plen,
+                               prompts[req, jnp.clip(slot_t, 0, p_max - 1)],
+                               state.last_tok)
+            logits, new_caches = decode_all(params, tok_in[:, None, None],
+                                            caches)
+            next_tok = jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
 
-        # -- record / retire ----------------------------------------------------
-        advance = occ & live
-        gen_i = slot_t - (plen - 1)
-        budget = lane.max_new[req]
-        rec = advance & (gen_i >= 0) & (gen_i < budget)
-        out_tokens = state.out_tokens.at[
-            jnp.where(rec, req, n_req), jnp.clip(gen_i, 0, max_new - 1)
-        ].set(next_tok, mode="drop")
-        finished = rec & (gen_i == budget - 1)
-        done = state.done.at[jnp.where(finished, req, n_req)].set(
-            True, mode="drop")
-        slot_t = jnp.where(advance, slot_t + 1, slot_t)
-        last_tok = jnp.where(advance, next_tok, state.last_tok)
-        caches = jax.tree.map(
-            lambda new, old: jnp.where(
-                advance.reshape((slots,) + (1,) * (new.ndim - 1)), new, old),
-            new_caches, caches)
-        slot_req = jnp.where(finished, n_req, slot_req)
+        with jax.named_scope("serve.retire"):
+            # -- record / retire --------------------------------------------
+            advance = occ & live
+            gen_i = slot_t - (plen - 1)
+            budget = lane.max_new[req]
+            rec = advance & (gen_i >= 0) & (gen_i < budget)
+            out_tokens = state.out_tokens.at[
+                jnp.where(rec, req, n_req), jnp.clip(gen_i, 0, max_new - 1)
+            ].set(next_tok, mode="drop")
+            finished = rec & (gen_i == budget - 1)
+            done = state.done.at[jnp.where(finished, req, n_req)].set(
+                True, mode="drop")
+            slot_t = jnp.where(advance, slot_t + 1, slot_t)
+            last_tok = jnp.where(advance, next_tok, state.last_tok)
+        with jax.named_scope("serve.cache_write"):
+            caches = jax.tree.map(
+                lambda new, old: jnp.where(
+                    advance.reshape((slots,) + (1,) * (new.ndim - 1)),
+                    new, old),
+                new_caches, caches)
+        with jax.named_scope("serve.retire"):
+            slot_req = jnp.where(finished, n_req, slot_req)
 
-        new_state = ServeState(
-            caches=caches, slot_req=slot_req, slot_t=slot_t,
-            last_tok=last_tok, admitted=admitted, done=done,
-            balances=balances, out_tokens=out_tokens)
-        record = ServeRecord(
-            coverage=coverage, live=live,
-            n_active=jnp.sum(occ).astype(jnp.int32),
-            n_admitted=jnp.sum(admit).astype(jnp.int32),
-            new_tokens=jnp.sum(rec).astype(jnp.int32),
-            # serviceable backlog only: credential-refused waiters are not
-            # demand (they would otherwise poison the availability metric
-            # — and hence the served/degraded classification — forever)
-            queued=(jnp.sum(waiting & funded)
-                    - jnp.sum(admit)).astype(jnp.int32))
+            new_state = ServeState(
+                caches=caches, slot_req=slot_req, slot_t=slot_t,
+                last_tok=last_tok, admitted=admitted, done=done,
+                balances=balances, out_tokens=out_tokens,
+                admit_step=admit_step)
+            record = ServeRecord(
+                coverage=coverage, live=live,
+                n_active=jnp.sum(occ).astype(jnp.int32),
+                n_admitted=jnp.sum(admit).astype(jnp.int32),
+                new_tokens=jnp.sum(rec).astype(jnp.int32),
+                # serviceable backlog only: credential-refused waiters are
+                # not demand (they would otherwise poison the availability
+                # metric — and hence the served/degraded classification —
+                # forever)
+                queued=(jnp.sum(waiting & funded)
+                        - jnp.sum(admit)).astype(jnp.int32))
         return new_state, record
 
     def init_state(lane: ServeLane) -> ServeState:
@@ -404,7 +418,8 @@ def make_serve_step(model, cfg: ServingConfig, prompt_shape: Tuple[int, int],
             admitted=jnp.zeros((n_req,), bool),
             done=jnp.zeros((n_req,), bool),
             balances=lane.balances.astype(jnp.float32),
-            out_tokens=jnp.zeros((n_req, max_new), jnp.int32))
+            out_tokens=jnp.zeros((n_req, max_new), jnp.int32),
+            admit_step=jnp.full((n_req,), -1, jnp.int32))
 
     return step, init_state
 
@@ -425,6 +440,9 @@ class ServeResult:
     n_admitted: np.ndarray    # (T,) int32
     new_tokens: np.ndarray    # (T,) int32
     queued: np.ndarray        # (T,) int32
+    admit_step: np.ndarray    # (R,) int32 — admission step, -1 = never:
+                              #   the first token comes out at step
+                              #   admit_step + prompt_len - 1
     wall_s: float = 0.0
 
     @property
@@ -481,6 +499,7 @@ def _result_from_device(state: ServeState, recs: ServeRecord,
         n_admitted=np.asarray(recs.n_admitted),
         new_tokens=np.asarray(recs.new_tokens),
         queued=np.asarray(recs.queued),
+        admit_step=np.asarray(state.admit_step),
         wall_s=wall_s)
 
 
@@ -567,11 +586,18 @@ class ServingEngine:
 
     def run(self, params, lane: ServeLane,
             prompts: Optional[Array] = None) -> ServeResult:
-        p = self._check(lane, prompts)
-        fn = self._program(lane.custody is not None, False)
-        t0 = time.perf_counter()
-        state, recs = jax.block_until_ready(fn(params, p, lane))
-        return _result_from_device(state, recs, time.perf_counter() - t0)
+        """Serve one lane.  Host spans (free when no profiler runs):
+        ``repro.serve.run`` around the call, ``.wait`` for the device to
+        finish the episode, ``.readback`` for its results."""
+        with jax.profiler.TraceAnnotation("repro.serve.run"):
+            p = self._check(lane, prompts)
+            fn = self._program(lane.custody is not None, False)
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("repro.serve.wait"):
+                state, recs = jax.block_until_ready(fn(params, p, lane))
+            wall_s = time.perf_counter() - t0
+            with jax.profiler.TraceAnnotation("repro.serve.readback"):
+                return _result_from_device(state, recs, wall_s)
 
     def run_many(self, params, lanes: ServeLane,
                  prompts: Optional[Array] = None) -> List[ServeResult]:

@@ -655,73 +655,87 @@ def make_round_fn(loss_fn: Callable, optimizer, params_template, n_nodes: int, *
             raise ValueError("staleness_bound > 0 needs a LaneParams.delays "
                              "lane (build it via lane_for_nodes with "
                              "SwarmConfig.staleness_bound set)")
-        active = (lane.joins <= rnd) & (rnd < lane.leaves) & (~state.slashed)
+        # Every operation of the round sits under one stage scope
+        # (``jax.named_scope``, op metadata only: the compiled program is
+        # the same).  ``repro.analysis.stages`` reads the stages back from
+        # the compiled program, so a profiler's device time joins to them.
+        # The roster mask and the key schedule open the gradient stage.
         econ = lane.econ
-        if econ is not None:
-            if decentralized:
-                raise ValueError("economy lanes need a centralized round "
-                                 "(stake-gated admission and the fee market "
-                                 "assume one aggregate)")
-            if state.econ is None:
-                raise ValueError("economy lane without SwarmState.econ — "
-                                 "init the state with "
-                                 "economy.init_econ_state(lane.econ, n)")
-            # stake-gated admission, derived in-program from live stakes:
-            # de-admitted nodes vanish from gradients, audits, aggregation
-            # masks, minting, and coverage alike
-            active = active & economy.admitted_mask(econ, state.econ)
-        nact = jnp.sum(active.astype(jnp.float32))
+        with jax.named_scope("swarm.grad"):
+            active = ((lane.joins <= rnd) & (rnd < lane.leaves)
+                      & (~state.slashed))
+            if econ is not None:
+                if decentralized:
+                    raise ValueError("economy lanes need a centralized round "
+                                     "(stake-gated admission and the fee "
+                                     "market assume one aggregate)")
+                if state.econ is None:
+                    raise ValueError("economy lane without SwarmState.econ "
+                                     "— init the state with "
+                                     "economy.init_econ_state(lane.econ, n)")
+                # stake-gated admission, derived in-program from live
+                # stakes: de-admitted nodes vanish from gradients, audits,
+                # aggregation masks, minting, and coverage alike
+                active = active & economy.admitted_mask(econ, state.econ)
+            nact = jnp.sum(active.astype(jnp.float32))
 
-        # the whole (purpose, round, node) fold_in schedule in three batched
-        # call sites — same keys as _node_key per (purpose, rnd, i), but the
-        # compiler sees 3 threefry kernels instead of 12+ (sweeps are
-        # compile-bound, and threefry dominates the round's compile cost).
-        # Synchronous rounds don't trace the _DELAY purpose at all.
-        pk = jax.vmap(lambda p: jax.random.fold_in(lane.base_key, p))(
-            jnp.arange(5 if staleness_bound > 0 else 4))
-        rk = jax.vmap(lambda k: jax.random.fold_in(k, rnd))(pk)
-        allk = jax.vmap(lambda k: jax.vmap(
-            lambda i: jax.random.fold_in(k, i))(idx))(rk)         # (P, N, 2)
-        ck, wk, sk, nk = allk[_CORRUPT], allk[_WIRE], \
-            allk[_AUDIT_SEL], allk[_AUDIT_NOISE]
+            # the whole (purpose, round, node) fold_in schedule in three
+            # batched call sites — same keys as _node_key per (purpose,
+            # rnd, i), but the compiler sees 3 threefry kernels instead of
+            # 12+ (sweeps are compile-bound, and threefry dominates the
+            # round's compile cost).  Synchronous rounds don't trace the
+            # _DELAY purpose at all.
+            pk = jax.vmap(lambda p: jax.random.fold_in(lane.base_key, p))(
+                jnp.arange(5 if staleness_bound > 0 else 4))
+            rk = jax.vmap(lambda k: jax.random.fold_in(k, rnd))(pk)
+            allk = jax.vmap(lambda k: jax.vmap(
+                lambda i: jax.random.fold_in(k, i))(idx))(rk)     # (P, N, 2)
+            ck, wk, sk, nk = allk[_CORRUPT], allk[_WIRE], \
+                allk[_AUDIT_SEL], allk[_AUDIT_NOISE]
 
-        if staleness_bound > 0:
-            # async round: snapshot first (slot r % (K+1) holds the params
-            # as of the start of round r — a realized delay of 0 reads the
-            # same params the synchronous round would), then per-node
-            # realized delays, then gradients at the gathered snapshots.
-            ring_len = jnp.int32(staleness_bound + 1)
-            ring = jax.tree.map(
-                lambda r, l: r.at[jnp.mod(rnd, ring_len)].set(l),
-                state.ring, state.params)
-            cap = jnp.minimum(jnp.minimum(lane.delays, rnd),
-                              jnp.int32(staleness_bound))
-            delay = jax.vmap(
-                lambda k, m: jax.random.randint(k, (), 0, m + jnp.int32(1)))(
-                allk[_DELAY], cap)
-            slots = jnp.mod(rnd - delay, ring_len)                # (N,)
-            if decentralized:
-                # ring leaves are (K+1, N, ...): node i reads its OWN
-                # replica as of round rnd - delay[i]
-                delayed = jax.tree.map(lambda r: r[slots, idx], ring)
+            if staleness_bound > 0:
+                # async round: snapshot first (slot r % (K+1) holds the
+                # params as of the start of round r — a realized delay of 0
+                # reads the same params the synchronous round would), then
+                # per-node realized delays, then gradients at the gathered
+                # snapshots.
+                ring_len = jnp.int32(staleness_bound + 1)
+                ring = jax.tree.map(
+                    lambda r, l: r.at[jnp.mod(rnd, ring_len)].set(l),
+                    state.ring, state.params)
+                cap = jnp.minimum(jnp.minimum(lane.delays, rnd),
+                                  jnp.int32(staleness_bound))
+                delay = jax.vmap(
+                    lambda k, m: jax.random.randint(
+                        k, (), 0, m + jnp.int32(1)))(allk[_DELAY], cap)
+                slots = jnp.mod(rnd - delay, ring_len)            # (N,)
+                if decentralized:
+                    # ring leaves are (K+1, N, ...): node i reads its OWN
+                    # replica as of round rnd - delay[i]
+                    delayed = jax.tree.map(lambda r: r[slots, idx], ring)
+                else:
+                    delayed = jax.tree.map(lambda r: r[slots], ring)
+                grads = jax.vmap(grad_fn, in_axes=(0, 0))(delayed, batches)
+                staleness = (jnp.sum(delay.astype(jnp.float32)
+                                     * active.astype(jnp.float32))
+                             / jnp.maximum(nact, 1.0))
             else:
-                delayed = jax.tree.map(lambda r: r[slots], ring)
-            grads = jax.vmap(grad_fn, in_axes=(0, 0))(delayed, batches)
-            staleness = (jnp.sum(delay.astype(jnp.float32)
-                                 * active.astype(jnp.float32))
-                         / jnp.maximum(nact, 1.0))
-        else:
-            # decentralized: every node gradients its OWN replica (leading
-            # node axis on state.params); centralized: one shared params
-            ring = state.ring
-            grad_axes = (0, 0) if decentralized else (None, 0)
-            grads = jax.vmap(grad_fn, in_axes=grad_axes)(state.params,
-                                                         batches)
-            staleness = jnp.zeros((), jnp.float32)
-        gf = flatten_stack(grads)                                 # (N, D)
-        maskf = active.astype(jnp.float32)[:, None]
-        honest_mean = jnp.sum(gf * maskf, axis=0) / jnp.maximum(nact, 1.0)
-        corrupted = _corrupt_all(lane.codes, gf, honest_mean, lane.scales, ck)
+                # decentralized: every node gradients its OWN replica
+                # (leading node axis on state.params); centralized: one
+                # shared params
+                ring = state.ring
+                grad_axes = (0, 0) if decentralized else (None, 0)
+                grads = jax.vmap(grad_fn, in_axes=grad_axes)(state.params,
+                                                             batches)
+                staleness = jnp.zeros((), jnp.float32)
+        with jax.named_scope("swarm.flatten"):
+            gf = flatten_stack(grads)                             # (N, D)
+            maskf = active.astype(jnp.float32)[:, None]
+            honest_mean = (jnp.sum(gf * maskf, axis=0)
+                           / jnp.maximum(nact, 1.0))
+        with jax.named_scope("swarm.corrupt"):
+            corrupted = _corrupt_all(lane.codes, gf, honest_mean, lane.scales,
+                                     ck)
 
         def route_aggs(fns, stack, mask):
             if route_kwargs:
@@ -739,120 +753,141 @@ def make_round_fn(loss_fn: Callable, optimizer, params_template, n_nodes: int, *
             # anticipated active mask — and overrides its fixed behaviour
             # with the best response.  One traced computation, like the
             # audit recompute; fixed (adaptive=0) lanes select it away.
-            coal_act = econ.coalition & active
-            best = economy.best_response_scale(
-                lambda s, m: route_aggs(ref_agg_fns, s, m),
-                gf, honest_mean, coal_act, active)
-            use_adaptive = (econ.adaptive > 0) & coal_act
-            corrupted = jnp.where(use_adaptive[:, None],
-                                  -best * honest_mean[None, :], corrupted)
+            with jax.named_scope("swarm.corrupt"):
+                coal_act = econ.coalition & active
+                best = economy.best_response_scale(
+                    lambda s, m: route_aggs(ref_agg_fns, s, m),
+                    gf, honest_mean, coal_act, active)
+                use_adaptive = (econ.adaptive > 0) & coal_act
+                corrupted = jnp.where(use_adaptive[:, None],
+                                      -best * honest_mean[None, :], corrupted)
 
-        if fused_qsgd:
-            submitted = jax.vmap(wire_payload)(wk, corrupted)
-        else:
-            submitted = jax.vmap(wire)(wk, corrupted)
+        with jax.named_scope("swarm.wire"):
+            if fused_qsgd:
+                submitted = jax.vmap(wire_payload)(wk, corrupted)
+            else:
+                submitted = jax.vmap(wire)(wk, corrupted)
 
-        caught = jnp.zeros(n_nodes, bool)
-        if verify:                           # static: baked at trace time
-            # audit rate / tolerance / noise are *traced* (array-valued
-            # VerificationConfig fields), so one program serves lanes with
-            # different p_check — including p_check == 0 (never audited).
-            vcfg = VerificationConfig(p_check=lane.p_check,
-                                      tolerance=lane.tolerance,
-                                      numeric_noise=lane.numeric_noise)
-            sel = jax.vmap(jax.random.uniform)(sk)
-            audited = active & (sel < lane.p_check)
-            # the validator recomputes the honest gradient and re-encodes it
-            # with the submitter's wire key (see SequentialSwarm.step).  In
-            # async rounds gf is the *delayed* gradient stack, so the
-            # recompute runs against the same stale snapshot the contributor
-            # claims — the delay is reproducible from the shared key
-            # schedule, which is what keeps the §4.2 audit sound under
-            # asynchrony (honest-but-stale is never slashed as cheating).
-            recomputed = jax.vmap(wire)(wk, gf)
-            audited_view = (qsgd_decode_ops.wire_decode(submitted)
-                            if fused_qsgd else submitted)
-            passes, _ = audit_batch(audited_view, recomputed, nk, vcfg)
-            caught = audited & (~passes)
-        keep = active & (~caught)
+        with jax.named_scope("swarm.audit"):
+            caught = jnp.zeros(n_nodes, bool)
+            if verify:                       # static: baked at trace time
+                # audit rate / tolerance / noise are *traced* (array-valued
+                # VerificationConfig fields), so one program serves lanes
+                # with different p_check — including p_check == 0 (never
+                # audited).
+                vcfg = VerificationConfig(p_check=lane.p_check,
+                                          tolerance=lane.tolerance,
+                                          numeric_noise=lane.numeric_noise)
+                sel = jax.vmap(jax.random.uniform)(sk)
+                audited = active & (sel < lane.p_check)
+                # the validator recomputes the honest gradient and
+                # re-encodes it with the submitter's wire key (see
+                # SequentialSwarm.step).  In async rounds gf is the
+                # *delayed* gradient stack, so the recompute runs against
+                # the same stale snapshot the contributor claims — the
+                # delay is reproducible from the shared key schedule, which
+                # is what keeps the §4.2 audit sound under asynchrony
+                # (honest-but-stale is never slashed as cheating).
+                recomputed = jax.vmap(wire)(wk, gf)
+                audited_view = (qsgd_decode_ops.wire_decode(submitted)
+                                if fused_qsgd else submitted)
+                passes, _ = audit_batch(audited_view, recomputed, nk, vcfg)
+                caught = audited & (~passes)
+            keep = active & (~caught)
 
         def run_aggs(mask):
             return route_aggs(agg_fns, submitted, mask)
 
         if decentralized:
-            w = lane.mixing.astype(jnp.float32)
-            if w.ndim == 3:              # time-varying / churn-coupled stack
-                t_max = w.shape[0]
-                w = w[jnp.minimum(rnd, t_max - 1)
-                      if mixing_schedule == "clamp" else jnp.mod(rnd, t_max)]
-            # node i robust-aggregates its neighborhood's kept submissions
-            # (Metropolis W has self-loops, so i's own update is in its set)
-            per_keep = (w > 0) & keep[None, :]            # (N, N)
-            agg = jax.vmap(run_aggs)(per_keep)            # (N, D)
-            node_any = jnp.any(per_keep, axis=1)
-            agg = jnp.where(node_any[:, None], agg, jnp.zeros_like(agg))
-            new_params, new_opt = jax.vmap(
-                lambda ok, a, p, o: jax.lax.cond(
-                    ok,
-                    lambda p, o: optimizer.update(unflatten(a), o, p),
+            with jax.named_scope("swarm.gossip"):
+                w = lane.mixing.astype(jnp.float32)
+                if w.ndim == 3:          # time-varying / churn-coupled stack
+                    t_max = w.shape[0]
+                    w = w[jnp.minimum(rnd, t_max - 1)
+                          if mixing_schedule == "clamp"
+                          else jnp.mod(rnd, t_max)]
+            with jax.named_scope("swarm.aggregate"):
+                # node i robust-aggregates its neighborhood's kept
+                # submissions (Metropolis W has self-loops, so i's own
+                # update is in its set)
+                per_keep = (w > 0) & keep[None, :]        # (N, N)
+                agg = jax.vmap(run_aggs)(per_keep)        # (N, D)
+                node_any = jnp.any(per_keep, axis=1)
+                agg = jnp.where(node_any[:, None], agg, jnp.zeros_like(agg))
+            with jax.named_scope("swarm.update"):
+                new_params, new_opt = jax.vmap(
+                    lambda ok, a, p, o: jax.lax.cond(
+                        ok,
+                        lambda p, o: optimizer.update(unflatten(a), o, p),
+                        lambda p, o: (p, o),
+                        p, o))(node_any, agg, state.params, state.opt_state)
+            with jax.named_scope("swarm.gossip"):
+                # gossip mix the replicas (momentum stays local — standard
+                # DSGD)
+                mixed = w @ flatten_stack(new_params)     # (N, P)
+                new_params = jax.vmap(unflatten)(mixed)
+                # consensus over *active* replicas only: under
+                # churn-coupled mixing a departed node's replica freezes
+                # (its row is e_i) and would otherwise dominate the max
+                # forever
+                mean_act = (jnp.sum(mixed * maskf, axis=0, keepdims=True)
+                            / jnp.maximum(nact, 1.0))
+                consensus_err = jnp.max(
+                    jnp.linalg.norm((mixed - mean_act) * maskf, axis=1))
+            with jax.named_scope("swarm.aggregate"):
+                agg_norm = jnp.mean(jax.vmap(jnp.linalg.norm)(agg))
+        else:
+            with jax.named_scope("swarm.aggregate"):
+                agg = run_aggs(keep)
+                any_keep = jnp.any(keep)
+                agg = jnp.where(any_keep, agg, jnp.zeros_like(agg))
+            with jax.named_scope("swarm.update"):
+                new_params, new_opt = jax.lax.cond(
+                    any_keep,
+                    lambda p, o: optimizer.update(unflatten(agg), o, p),
                     lambda p, o: (p, o),
-                    p, o))(node_any, agg, state.params, state.opt_state)
-            # gossip mix the replicas (momentum stays local — standard DSGD)
-            mixed = w @ flatten_stack(new_params)         # (N, P)
-            new_params = jax.vmap(unflatten)(mixed)
-            # consensus over *active* replicas only: under churn-coupled
-            # mixing a departed node's replica freezes (its row is e_i) and
-            # would otherwise dominate the max forever
-            mean_act = (jnp.sum(mixed * maskf, axis=0, keepdims=True)
-                        / jnp.maximum(nact, 1.0))
-            consensus_err = jnp.max(
-                jnp.linalg.norm((mixed - mean_act) * maskf, axis=1))
-            agg_norm = jnp.mean(jax.vmap(jnp.linalg.norm)(agg))
-        else:
-            agg = run_aggs(keep)
-            any_keep = jnp.any(keep)
-            agg = jnp.where(any_keep, agg, jnp.zeros_like(agg))
-            new_params, new_opt = jax.lax.cond(
-                any_keep,
-                lambda p, o: optimizer.update(unflatten(agg), o, p),
-                lambda p, o: (p, o),
-                state.params, state.opt_state)
+                    state.params, state.opt_state)
             consensus_err = jnp.zeros((), jnp.float32)
-            agg_norm = jnp.linalg.norm(agg)
+            with jax.named_scope("swarm.aggregate"):
+                agg_norm = jnp.linalg.norm(agg)
 
-        # custody observability: the live extraction frontier — a shard is
-        # available while >= 1 holder is active (custody-coupled churn:
-        # departed/slashed holders zero their shards' availability)
-        if lane.custody is not None:
-            coverage = jnp.mean(jnp.any(lane.custody & active[:, None],
-                                        axis=0).astype(jnp.float32))
-        else:
-            coverage = jnp.ones((), jnp.float32)
+        with jax.named_scope("swarm.record"):
+            # custody observability: the live extraction frontier — a
+            # shard is available while >= 1 holder is active
+            # (custody-coupled churn: departed/slashed holders zero their
+            # shards' availability)
+            if lane.custody is not None:
+                coverage = jnp.mean(jnp.any(lane.custody & active[:, None],
+                                            axis=0).astype(jnp.float32))
+            else:
+                coverage = jnp.ones((), jnp.float32)
 
-        new_econ, coalition_stake = state.econ, None
-        if econ is not None:
-            new_econ = economy.econ_round_update(
-                econ, state.econ, active=active, keep=keep, caught=caught,
-                speeds=lane.speeds)
-            fkeep = keep.astype(jnp.float32)
-            act_stake = jnp.sum(new_econ.stake * fkeep)
-            coal_stake = jnp.sum(new_econ.stake * fkeep
-                                 * econ.coalition.astype(jnp.float32))
-            coalition_stake = jnp.where(
-                act_stake > 0.0, coal_stake / jnp.maximum(act_stake, 1e-9),
-                jnp.zeros((), jnp.float32))
+            new_econ, coalition_stake = state.econ, None
+            if econ is not None:
+                new_econ = economy.econ_round_update(
+                    econ, state.econ, active=active, keep=keep,
+                    caught=caught, speeds=lane.speeds)
+                fkeep = keep.astype(jnp.float32)
+                act_stake = jnp.sum(new_econ.stake * fkeep)
+                coal_stake = jnp.sum(new_econ.stake * fkeep
+                                     * econ.coalition.astype(jnp.float32))
+                coalition_stake = jnp.where(
+                    act_stake > 0.0,
+                    coal_stake / jnp.maximum(act_stake, 1e-9),
+                    jnp.zeros((), jnp.float32))
 
-        new_state = SwarmState(
-            params=new_params, opt_state=new_opt,
-            slashed=state.slashed | caught,
-            contrib=state.contrib + lane.speeds * keep.astype(jnp.float32),
-            ring=ring, econ=new_econ)
-        rec = RoundRecord(
-            n_active=jnp.sum(active).astype(jnp.int32),
-            n_byzantine=jnp.sum(active & (lane.codes > 0)).astype(jnp.int32),
-            caught=caught, keep=keep, agg_norm=agg_norm,
-            consensus_err=consensus_err, coverage=coverage,
-            staleness=staleness, coalition_stake=coalition_stake)
+            new_state = SwarmState(
+                params=new_params, opt_state=new_opt,
+                slashed=state.slashed | caught,
+                contrib=state.contrib + lane.speeds * keep.astype(jnp.float32),
+                ring=ring, econ=new_econ)
+            rec = RoundRecord(
+                n_active=jnp.sum(active).astype(jnp.int32),
+                n_byzantine=jnp.sum(active & (lane.codes > 0)).astype(
+                    jnp.int32),
+                caught=caught, keep=keep, agg_norm=agg_norm,
+                consensus_err=consensus_err, coverage=coverage,
+                staleness=staleness, coalition_stake=coalition_stake)
         return new_state, rec
 
     round_fn.fused = fused                    # resolved choice, inspectable
@@ -1468,19 +1503,33 @@ class Swarm(_SwarmBase):
 
     # -- one round ----------------------------------------------------------------
     def step(self, rnd: int) -> dict:
-        active_np = ((self._joins_np <= rnd) & (rnd < self._leaves_np)
-                     & ~self._slashed_np)
-        if not active_np.any():
-            raise RuntimeError(f"round {rnd}: no active nodes")
+        """One round.  Host spans (``jax.profiler.TraceAnnotation``, free
+        when no profiler runs) split it in the trace: ``feed`` builds the
+        batch, ``dispatch`` enqueues the round, ``wait`` is the first read
+        of its results (the host waits for the device there), ``settle``
+        reads the rest, slashes, and fills the ledger and the history."""
+        with jax.profiler.TraceAnnotation("repro.swarm.step"):
+            active_np = ((self._joins_np <= rnd) & (rnd < self._leaves_np)
+                         & ~self._slashed_np)
+            if not active_np.any():
+                raise RuntimeError(f"round {rnd}: no active nodes")
 
-        batches = self._stack_batches(rnd)
-        state, core_rec = self._round_fn(self._state(), rnd, batches)
-        self.params, self.opt_state = state.params, state.opt_state
-        self._ring = state.ring
-        self._econ_state = state.econ
+            with jax.profiler.TraceAnnotation("repro.swarm.feed"):
+                batches = self._stack_batches(rnd)
+            with jax.profiler.TraceAnnotation("repro.swarm.dispatch"):
+                state, core_rec = self._round_fn(self._state(), rnd, batches)
+            self.params, self.opt_state = state.params, state.opt_state
+            self._ring = state.ring
+            self._econ_state = state.econ
 
+            with jax.profiler.TraceAnnotation("repro.swarm.wait"):
+                caught = np.asarray(core_rec.caught)
+            with jax.profiler.TraceAnnotation("repro.swarm.settle"):
+                return self._settle(rnd, active_np, caught, core_rec)
+
+    def _settle(self, rnd: int, active_np, caught, core_rec) -> dict:
         caught_ids = []
-        for i in np.flatnonzero(np.asarray(core_rec.caught)):
+        for i in np.flatnonzero(caught):
             node = self.nodes[int(i)]
             self._slash(node)
             self._slashed_np[int(i)] = True

@@ -66,5 +66,6 @@ def centered_clip_iter_fwd(updates, v, *, clip_tau: float = 1.0,
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
         interpret=interpret,
+        name="centered_clip_iter",
     )(updates, v.reshape(1, d))
     return out.reshape(d)

@@ -109,5 +109,6 @@ def ssd_scan_fwd(x, adt, b, c, h0, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xr, ar.reshape(bsz, nc, chunk, h), br, cr, h0.astype(jnp.float32))
     return y.reshape(bsz, s, h, p), hf

@@ -150,6 +150,7 @@ def masked_median_fwd(updates, mask, *, block_d: int = 2048,
         out_specs=pl.BlockSpec((1, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="masked_median",
     )(updates, mask.reshape(n, 1).astype(jnp.float32))
     return out.reshape(d)[:d0]
 
@@ -213,6 +214,7 @@ def masked_cc_iter_fwd(updates, v, mask, *, clip_tau=None,
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
         interpret=interpret,
+        name="masked_cc_iter",
     )(updates, v.reshape(1, d), mask.reshape(n, 1).astype(jnp.float32))
     return out.reshape(d)[:d0]
 
@@ -248,4 +250,5 @@ def masked_krum_d2_fwd(updates, *, block_d: int = 2048,
         out_specs=pl.BlockSpec((n, n), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         interpret=interpret,
+        name="masked_krum_d2",
     )(updates)

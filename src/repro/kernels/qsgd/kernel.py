@@ -63,4 +63,5 @@ def qsgd_encode_fwd(x2d, rnd2d, norm, *, levels: int = 64,
         ),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.int8),
         interpret=interpret,
+        name="qsgd_encode",
     )(norm.reshape(1), x2d, rnd2d)

@@ -85,5 +85,6 @@ def qsgd_decode_accumulate_fwd(codes, norms, weights, *, levels: int,
         out_specs=pl.BlockSpec((1, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, l), jnp.float32),
         interpret=interpret,
+        name="qsgd_decode_acc",
     )(codes, grouped, weights.reshape(n, 1).astype(jnp.float32))
     return out.reshape(l)

@@ -102,5 +102,6 @@ def wkv_scan_fwd(r, k, v, logw, u, s0, *, chunk: int = 64,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dk), jnp.float32)],
         interpret=interpret,
+        name="wkv_scan",
     )(resh(r), resh(k), resh(v), resh(logw), u, s0.astype(jnp.float32))
     return y.reshape(bsz, s, h, dk), sf

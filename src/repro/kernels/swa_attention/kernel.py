@@ -104,4 +104,5 @@ def swa_attention_fwd(q, k, v, *, window: int, block_q: int = 128,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="swa_attention",
     )(q, k, v)

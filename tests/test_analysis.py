@@ -147,7 +147,7 @@ def _call(grid, in_specs, in_shapes, out_specs, out_shapes, scratch=0):
         kernel="golden", index=0, grid=grid,
         in_specs=in_specs, out_specs=out_specs,
         in_shapes=in_shapes, out_shapes=out_shapes,
-        scratch_bytes=scratch, num_scalar_prefetch=0)
+        scratch_bytes=scratch, num_scalar_prefetch=0, name="golden")
 
 
 def test_pk001_fires_on_uncovered_output_tile():
@@ -224,6 +224,21 @@ def test_pk004_fires_on_sub_sublane_second_minor():
     call = _call((4,), [_Spec((4, 128), lambda i: (i, 0))], [((16, 128), 4)],
                  [_Spec((4, 128), lambda i: (i, 0))], [((16, 128), 4)])
     assert "PK004" in _codes(pallas_check._check_call(call))
+
+
+def test_pk005_fires_on_unnamed_call():
+    call = _call((2,), [], [], [_Spec((128,), lambda i: (i,))], [((256,), 4)])
+    call.name = None
+    assert _codes(pallas_check._check_call(call)) == {"PK005"}
+
+
+def test_pk005_quiet_on_every_registered_kernel():
+    """Every kernel probe's calls carry a name: the trace shows each
+    Mosaic call under it, never as ``_unknown_``."""
+    for name in pallas_check.KERNEL_PROBES:
+        violations, records = pallas_check.check_kernel(name)
+        assert records and all(r.name for r in records), name
+        assert "PK005" not in _codes(violations)
 
 
 # =============================== PL rules =====================================
@@ -384,7 +399,7 @@ def test_noqa_suppresses_a_rule():
 def test_rule_catalog_is_complete():
     assert set(RULES) == {
         "JX001", "JX002", "JX003", "JX004", "JX005", "JX006", "JX007",
-        "PK001", "PK002", "PK003", "PK004",
+        "PK001", "PK002", "PK003", "PK004", "PK005",
         "PL000", "PL001", "PL002", "PL003", "PL004", "PL005"}
 
 

@@ -268,6 +268,12 @@ def _probe_masked_agg():
     k.masked_cc_iter_fwd(upd, jnp.zeros((4000,), jnp.float32), mask,
                          block_d=2048)
     k.masked_krum_d2_fwd(upd, block_d=2048)
+    # no cap: the byte rule's tile, as the chip runs it — two of the
+    # 56,064-column tiles protocol-125m's (8, D) stack gets
+    upd = jnp.ones((8, 2 * 56064), jnp.float32)
+    k.masked_median_fwd(upd, mask)
+    k.masked_cc_iter_fwd(upd, jnp.zeros((2 * 56064,), jnp.float32), mask)
+    k.masked_krum_d2_fwd(upd)
 
 
 def _probe_centered_clip():

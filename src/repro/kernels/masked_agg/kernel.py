@@ -29,13 +29,26 @@ itself round-trips through HBM:
   revisited output block.  The O(N²) selection phase is left to plain jnp
   in ops.py — it touches nothing of size D.
 
-Grids: median/krum (n_d_blocks,); CC (2, n_d_blocks) phase-outermost.
-All kernels carry an ``interpret=True`` path so tier-1 pins them on CPU.
+Grids: median/krum (n_d_blocks,); CC (2, n_d_blocks) phase-outermost, its
+output block parked on tile 0 through phase 0 (``(0, j * ph)``), so each
+iteration writes the centre once.  All kernels carry an ``interpret=True``
+path so tier-1 pins them on CPU.
+
+The tile rule (``_fit_block``) sizes the stack tile by bytes: the widest
+lane multiple that divides the padded D with the (N, block_d) f32 tile, as
+VMEM holds it (N rounded up to whole 8-row sublane groups), within
+``TILE_BYTES``.  A grid step costs a fixed ~0.3 µs on a v5e whatever it
+moves, so the tile has to carry enough bytes to hide it, and a cap by
+column count does not see what D allows: under a 2048-column cap
+protocol-125m's D = 128 · 2 · 3 · 73 · 2897 gets 768-column tiles (24 KB,
+211,481 steps a pass), under the byte target 56,064 columns (1.8 MB, 2,897
+steps).  A D whose lane count has no divisor near the limit still gets a
+narrow tile: a prime D / 128 gets 128 columns.
 """
 from __future__ import annotations
 
 import functools
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,13 +98,24 @@ def _pad_lanes(x, *, mult: int = LANE):
     return x, d
 
 
-def _fit_block(d: int, block_d: int) -> int:
-    """Largest lane-multiple tile <= block_d that divides d (d is already a
-    lane multiple, so this bottoms out at LANE)."""
-    block_d = max(LANE, min(block_d, d) // LANE * LANE)
-    while d % block_d:
-        block_d -= LANE
-    return block_d
+SUBLANE = 8
+#: Bytes of one (N, block_d) f32 stack tile in VMEM: the tile rule's target.
+TILE_BYTES = 2 << 20
+
+
+def _fit_block(n: int, d: int, block_d: Optional[int] = None) -> int:
+    """Widest lane-multiple tile that divides d (d is already a lane
+    multiple, so this bottoms out at LANE) and keeps the (n, tile) f32
+    block, rows padded to whole sublane groups, within TILE_BYTES;
+    ``block_d``, when given, caps the tile further."""
+    rows = -(-n // SUBLANE) * SUBLANE
+    limit = TILE_BYTES // (4 * rows)
+    if block_d is not None:
+        limit = min(limit, block_d)
+    width = max(LANE, min(limit, d) // LANE * LANE)
+    while d % width:
+        width -= LANE
+    return width
 
 
 def _sorted_rows(rows: List[jax.Array]) -> List[jax.Array]:
@@ -130,15 +154,16 @@ def _median_kernel(x_ref, m_ref, o_ref, *, n: int):
     o_ref[...] = _masked_rank_interp(_sorted_rows(rows), k)
 
 
-def masked_median_fwd(updates, mask, *, block_d: int = 2048,
+def masked_median_fwd(updates, mask, *, block_d: Optional[int] = None,
                       interpret: bool = False):
     """Masked coordinate median.  updates (N, D) f32, mask (N,) -> (D,).
     Bit-equal to ``aggregation._masked_median`` for k >= 1 (all-masked
-    columns are meaningless — callers guard k == 0)."""
+    columns are meaningless — callers guard k == 0).  ``block_d`` caps the
+    tile the byte rule picks (None: no cap)."""
     n, d0 = updates.shape
     updates, _ = _pad_lanes(updates)
     d = updates.shape[1]
-    block_d = _fit_block(d, block_d)
+    block_d = _fit_block(n, d, block_d)
     kern = functools.partial(_median_kernel, n=n)
     out = pl.pallas_call(
         kern,
@@ -171,7 +196,6 @@ def _cc_kernel(x_ref, v_ref, m_ref, o_ref, sq_ref, *, n: int, tau):
     @pl.when(ph == 0)
     def _accumulate():
         sq_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
-        o_ref[...] = v_ref[...]                        # placeholder write
 
     @pl.when(ph == 1)
     def _apply():
@@ -191,15 +215,17 @@ def _cc_kernel(x_ref, v_ref, m_ref, o_ref, sq_ref, *, n: int, tau):
 
 
 def masked_cc_iter_fwd(updates, v, mask, *, clip_tau=None,
-                       block_d: int = 2048, interpret: bool = False):
+                       block_d: Optional[int] = None,
+                       interpret: bool = False):
     """One masked CenteredClip iteration: v ← v + Σᵢ mᵢ·clip(xᵢ − v, τ)/k.
     updates (N, D) f32, v (D,), mask (N,) -> (D,).  ``clip_tau=None``
-    selects the adaptive τ (masked median of ‖xᵢ − v‖)."""
+    selects the adaptive τ (masked median of ‖xᵢ − v‖).  ``block_d`` caps
+    the tile the byte rule picks (None: no cap)."""
     n, d0 = updates.shape
     updates, _ = _pad_lanes(updates)
     v, _ = _pad_lanes(v)
     d = updates.shape[1]
-    block_d = _fit_block(d, block_d)
+    block_d = _fit_block(n, d, block_d)
     kern = functools.partial(_cc_kernel, n=n,
                              tau=None if clip_tau is None else float(clip_tau))
     out = pl.pallas_call(
@@ -210,7 +236,9 @@ def masked_cc_iter_fwd(updates, v, mask, *, clip_tau=None,
             pl.BlockSpec((1, block_d), lambda ph, j: (0, j)),
             pl.BlockSpec((n, 1), lambda ph, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda ph, j: (0, j)),
+        # phase 0 writes nothing: its output block stays on tile 0, which
+        # phase 1 writes first, so each tile goes back to HBM once
+        out_specs=pl.BlockSpec((1, block_d), lambda ph, j: (0, j * ph)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
         interpret=interpret,
@@ -233,7 +261,7 @@ def _krum_d2_kernel(x_ref, o_ref):
     o_ref[...] += sq[:, None] + sq[None, :] - 2.0 * gram
 
 
-def masked_krum_d2_fwd(updates, *, block_d: int = 2048,
+def masked_krum_d2_fwd(updates, *, block_d: Optional[int] = None,
                        interpret: bool = False):
     """Pairwise squared distances (N, N) of the update stack, accumulated
     tile-by-tile in the gram form (one MXU matmul per tile).  The mask and
@@ -242,7 +270,7 @@ def masked_krum_d2_fwd(updates, *, block_d: int = 2048,
     n, _ = updates.shape
     updates, _ = _pad_lanes(updates)
     d = updates.shape[1]
-    block_d = _fit_block(d, block_d)
+    block_d = _fit_block(n, d, block_d)
     return pl.pallas_call(
         _krum_d2_kernel,
         grid=(d // block_d,),

@@ -67,7 +67,7 @@ def masked_median_net(updates: Array, mask: Array) -> Array:
 def masked_centered_clip_fused(updates, mask: Array, *,
                                clip_tau=None, iters: int = 3, v0=None,
                                use_kernel: Optional[bool] = None,
-                               block_d: int = 2048,
+                               block_d: Optional[int] = None,
                                interpret: Optional[bool] = None) -> Array:
     x = _as_f32_stack(updates)
     if use_kernels(use_kernel):
@@ -90,7 +90,7 @@ def masked_centered_clip_fused(updates, mask: Array, *,
 
 def masked_krum_fused(updates, mask: Array, *, f: int = 1,
                       use_kernel: Optional[bool] = None,
-                      block_d: int = 2048,
+                      block_d: Optional[int] = None,
                       interpret: Optional[bool] = None) -> Array:
     x = _as_f32_stack(updates)
     if use_kernels(use_kernel):

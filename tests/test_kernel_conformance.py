@@ -54,7 +54,8 @@ def _mask(name: str, n: int):
 MASKS = ["all_live", "churned", "single_survivor", "all_masked"]
 LIVE_MASKS = MASKS[:-1]
 # (5, 257): N not a power of two (network pads to 8) and D prime — forces
-# the kernel block_d halving loop all the way down and LANE/bucket padding
+# LANE/bucket padding, and under a 256 cap a 128-column tile (the widest
+# lane multiple dividing the padded 384)
 SHAPES = [(8, 512), (16, 1000), (5, 257)]
 
 
@@ -165,6 +166,54 @@ def test_krum_d2_kernel_matches_broadcast_reference():
     out = magg_kernel.masked_krum_d2_fwd(x, block_d=256, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-3)
+
+
+# ===================== masked_agg: the byte-sized default tile ================
+def _kernel_vs_reference(kind, x, m, block_d=None):
+    """(kernel output, reference) of one masked-aggregation kernel run with
+    the tile the byte rule picks (``block_d`` None) or under a cap."""
+    kw = {"block_d": block_d, "interpret": True}
+    if kind == "median":
+        return (magg_kernel.masked_median_fwd(x, m, **kw),
+                agg._masked_median(x, m))
+    if kind == "krum":
+        return (magg.masked_krum_fused(x, m, f=1, use_kernel=True, **kw),
+                agg.masked_krum(x, m, f=1))
+    tau = {"cc_adaptive": None, "cc_fixed": 0.7}[kind]
+    return (magg.masked_centered_clip_fused(x, m, clip_tau=tau, iters=3,
+                                            use_kernel=True, **kw),
+            agg.masked_centered_clip(x, m, clip_tau=tau, iters=3))
+
+
+def _assert_conforms(kind, out, ref):
+    if kind in ("median", "krum"):       # min/max network; a selected row
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    else:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("n,d", SHAPES)
+@pytest.mark.parametrize("mask_name", LIVE_MASKS)
+@pytest.mark.parametrize("kind", ["median", "cc_adaptive", "cc_fixed",
+                                  "krum"])
+def test_masked_agg_kernels_default_tile(n, d, mask_name, kind):
+    """With no cap the byte rule gives these widths one whole-row tile:
+    the kernels must conform there as they do on capped multi-tile grids."""
+    x = _stack(n, d)
+    _assert_conforms(kind, *_kernel_vs_reference(kind, x, _mask(mask_name, n)))
+
+
+@pytest.mark.parametrize("kind", ["median", "cc_adaptive", "cc_fixed",
+                                  "krum"])
+def test_masked_agg_kernels_odd_multi_tile(kind):
+    """(8, 128·73·2) under a 128·73 cap: two tiles of 9344 columns, a
+    lane multiple that is no power of two."""
+    assert magg_kernel._fit_block(8, 128 * 73 * 2, 128 * 73) == 128 * 73
+    x = _stack(8, 128 * 73 * 2)
+    out, ref = _kernel_vs_reference(kind, x, _mask("churned", 8),
+                                    block_d=128 * 73)
+    _assert_conforms(kind, out, ref)
 
 
 # ===================== all-masked guards (total churn) ========================

@@ -203,7 +203,7 @@ def build_campaign() -> TracedProgram:
 
     units = []
     for variant in ("base", "churn", "attack"):
-        closed = jax.make_jaxpr(fn)(_campaign_lanes(cfg, n, variant))
+        closed = jax.make_jaxpr(fn)(_campaign_lanes(cfg, n, variant), params)
         units.append(TracedUnit(variant, closed, group="campaign"))
 
     # mesh variant: same campaign under an explicit MeshPlan — placement and
@@ -215,7 +215,7 @@ def build_campaign() -> TracedProgram:
         loss_fn, plan.place_params(params), opt, data_fn, placed, rounds=2,
         aggregator="centered_clip", eval_fn=eval_fn, plan=plan)
     with plan.mesh:
-        closed = jax.make_jaxpr(mesh_fn)(placed)
+        closed = jax.make_jaxpr(mesh_fn)(placed, plan.place_params(params))
     units.append(TracedUnit(
         "mesh", closed, group="campaign_mesh",
         declared_axes=frozenset(
@@ -249,7 +249,7 @@ def build_sweep() -> TracedProgram:
     units = []
     for label, (seed, scale) in (("base", (0, 10.0)), ("shifted", (1, 50.0))):
         spec = derailment.build_sweep_lanes(_sweep_grid(seed, scale), rounds=2)
-        closed = jax.make_jaxpr(fn)(swarm.stack_lanes(spec.lanes))
+        closed = jax.make_jaxpr(fn)(swarm.stack_lanes(spec.lanes), params)
         units.append(TracedUnit(label, closed, group="sweep"))
     return TracedProgram("sweep", units)
 
@@ -311,7 +311,7 @@ def build_round_async() -> TracedProgram:
                 rounds=2, aggregator=spec.aggregator,
                 agg_kwargs=spec.agg_kwargs, verify=spec.verify,
                 eval_fn=eval_fn)
-        closed = jax.make_jaxpr(fn)(swarm.stack_lanes(spec.lanes))
+        closed = jax.make_jaxpr(fn)(swarm.stack_lanes(spec.lanes), params)
         units.append(TracedUnit(label, closed, group="campaign_async"))
 
     # the scanned async run donates the ring buffer next to opt_state +
@@ -365,7 +365,7 @@ def build_economy() -> TracedProgram:
                 rounds=2, aggregator=spec.aggregator,
                 agg_kwargs=spec.agg_kwargs, verify=spec.verify,
                 eval_fn=eval_fn)
-        closed = jax.make_jaxpr(fn)(swarm.stack_lanes(spec.lanes))
+        closed = jax.make_jaxpr(fn)(swarm.stack_lanes(spec.lanes), params)
         units.append(TracedUnit(label, closed, group="campaign_economy"))
 
     # the scanned economy run donates the EconState carry next to

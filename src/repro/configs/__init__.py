@@ -25,6 +25,7 @@ from repro.configs.tinyllama_1_1b import CONFIG as _tinyllama
 from repro.configs.qwen3_moe_30b_a3b import CONFIG as _qwen3_moe
 from repro.configs.seamless_m4t_medium import CONFIG as _seamless
 from repro.configs.protocol_125m import CONFIG as _protocol_125m
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 
 REGISTRY = {
     c.name: c
@@ -40,10 +41,14 @@ REGISTRY = {
         _qwen3_moe,
         _seamless,
         _protocol_125m,
+        _deepseek_v2_lite,
     )
 }
 
-ASSIGNED_ARCHS = [n for n in REGISTRY if n != "protocol-125m"]
+#: the assigned pool; protocol-125m is the paper's demonstrator and
+#: deepseek-v2-lite the benchmark's latent-attention expert model
+ASSIGNED_ARCHS = [n for n in REGISTRY
+                  if n not in ("protocol-125m", "deepseek-v2-lite")]
 
 
 def get_config(name: str) -> ModelConfig:

@@ -23,6 +23,21 @@ FAMILIES = (DENSE, MOE, HYBRID, SSM, VLM, AUDIO)
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type "yarn"): low frequencies interpolated by ``factor``, high ones kept,
+    a linear ramp between the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times over ``original_max_position_embeddings``; the
+    attention scale gains ``mscale(factor, mscale_all_dim)`` squared."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     # identity
     name: str
@@ -43,12 +58,32 @@ class ModelConfig:
     sliding_window: Optional[int] = None   # SWA window (tokens); None = full attention
     rope_theta: float = 10_000.0
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE (t, h, w)
+    yarn: Optional[YarnScaling] = None     # YaRN rope scaling (a dict is converted)
+
+    # multi-head latent attention (DeepSeek-V2, arXiv:2405.04434); 0 = off.
+    # Queries are projected whole (no q-LoRA); keys and values come from one
+    # kv_lora_rank-wide latent and one qk_rope_head_dim-wide roped key that
+    # every head shares.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     num_experts: int = 0                # 0 = dense FFN
     experts_per_token: int = 0          # top-k
     moe_capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.01
+    # DeepSeek-style MoE: leading dense layers, shared experts, and the
+    # routed experts held on this device.  n_routed_experts > 0 selects the
+    # held-expert layer (models/moe.py:held_moe_ffn): it routes over all
+    # num_experts and computes experts 0..n_routed_experts-1 only.
+    first_k_dense: int = 0              # leading layers with a dense FFN
+    dense_d_ff: int = 0                 # their SwiGLU width
+    num_shared_experts: int = 0         # one SwiGLU of width n * d_ff, every token
+    n_routed_experts: int = 0           # routed experts held here
+    norm_topk_prob: bool = True         # renormalize the top-k gates
+    routed_scaling_factor: float = 1.0  # gates' factor after top-k
 
     # SSM / Mamba2 (hybrid + zamba2)
     ssm_state_size: int = 0             # d_state
@@ -80,6 +115,10 @@ class ModelConfig:
     max_seq_len: int = 4096
     xent_chunk: int = 512               # sequence-chunked cross entropy
 
+    def __post_init__(self):
+        if isinstance(self.yarn, dict):        # from a JSON configuration
+            object.__setattr__(self, "yarn", YarnScaling(**self.yarn))
+
     # -- derived -------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
@@ -108,13 +147,20 @@ class ModelConfig:
         emb = v * d * (1 if self.tie_embeddings else 2)
 
         def attn_params() -> int:
+            if self.kv_lora_rank:             # MLA, with the latent's norm
+                r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+                nope, v_hd = self.qk_nope_head_dim, self.v_head_dim
+                return (d * n_q * (nope + rope) + d * (r + rope) + r
+                        + r * n_q * (nope + v_hd) + n_q * v_hd * d)
             return d * hd * n_q + 2 * d * hd * n_kv + hd * n_q * d
 
         def dense_ffn() -> int:
             return 3 * d * self.d_ff          # SwiGLU: gate, up, down
 
         def moe_ffn() -> int:
-            return self.num_experts * 3 * d * self.d_ff + d * self.num_experts
+            held = self.n_routed_experts or self.num_experts
+            return (held * 3 * d * self.d_ff + d * self.num_experts
+                    + 3 * d * self.num_shared_experts * self.d_ff)
 
         def mamba_block() -> int:
             d_in = self.ssm_expand * d
@@ -136,7 +182,9 @@ class ModelConfig:
             total = emb + self.num_layers * per_layer + d
         elif self.family == MOE:
             per_layer = attn_params() + moe_ffn() + norms
-            total = emb + self.num_layers * per_layer + d
+            lead = attn_params() + 3 * d * self.dense_d_ff + norms
+            total = (emb + (self.num_layers - self.first_k_dense) * per_layer
+                     + self.first_k_dense * lead + d)
         elif self.family == HYBRID:
             n_groups = self.num_layers // self.mamba_per_group
             total = (emb + self.num_layers * (mamba_block() + d)
@@ -157,8 +205,11 @@ class ModelConfig:
         if self.family != MOE:
             return self.param_count()
         full = self.param_count()
-        ffn_all = self.num_layers * self.num_experts * 3 * self.d_model * self.d_ff
-        ffn_active = self.num_layers * self.experts_per_token * 3 * self.d_model * self.d_ff
+        held = self.n_routed_experts or self.num_experts
+        moe_layers = self.num_layers - self.first_k_dense
+        expert = 3 * self.d_model * self.d_ff
+        ffn_all = moe_layers * held * expert
+        ffn_active = moe_layers * min(self.experts_per_token, held) * expert
         return int(full - ffn_all + ffn_active)
 
     def reduced(self, **overrides) -> "ModelConfig":
@@ -178,6 +229,13 @@ class ModelConfig:
         )
         if self.num_experts:
             small.update(num_experts=4, experts_per_token=min(2, self.experts_per_token))
+        if self.n_routed_experts:
+            small.update(n_routed_experts=4)
+        if self.first_k_dense:
+            small.update(first_k_dense=1, dense_d_ff=256)
+        if self.kv_lora_rank:
+            small.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                         qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm_state_size:
             small.update(ssm_state_size=16, ssm_head_dim=32, mamba_per_group=1)
         if self.family == SSM:

@@ -12,7 +12,9 @@ Two sharding levels, with different exactness contracts:
 - **lane axis** — the stacked :class:`~repro.core.swarm.LaneParams` /
   :class:`~repro.core.serving.ServeLane` leaves shard their leading run
   axis over ``lanes`` (``place_lanes``), and the engine's ``vmap`` carries
-  ``spmd_axis_name`` so internal sharding constraints stay lane-local.
+  ``spmd_axis_name`` so internal sharding constraints stay lane-local (a
+  campaign whose plan has lanes alone runs each device's block under
+  ``shard_map`` instead: its Pallas kernels cannot be partitioned).
   Lanes are embarrassingly parallel, so this is **bit-exact** against the
   unsharded engine run on each device's block of lanes, for the
   centralized, fused-kernel, and serving rounds (pinned in

@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import functools
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -240,6 +240,9 @@ class ServeRecord(NamedTuple):
     queued: Array         # () int32 — arrived, unadmitted, fundable after
                           #   this step (credential-refused waiters are
                           #   not counted as demand)
+    stats: Dict[str, Array]  # the model's per-step counters
+                          #   (``Model.decode_step_stats``) summed over the
+                          #   advancing slots; empty for most models
 
 
 def stack_serve_lanes(lanes: Sequence[ServeLane]) -> ServeLane:
@@ -290,7 +293,7 @@ def make_serve_step(model, cfg: ServingConfig, prompt_shape: Tuple[int, int],
     template = model.init_cache(1, cache_len)
 
     def decode_all(params, toks, caches):
-        return jax.vmap(model.decode_step,
+        return jax.vmap(model.decode_step_stats,
                         in_axes=(None, 0, 0))(params, toks, caches)
 
     def step(params, prompts: Array, lane: ServeLane, state: ServeState, t):
@@ -362,8 +365,8 @@ def make_serve_step(model, cfg: ServingConfig, prompt_shape: Tuple[int, int],
             tok_in = jnp.where(slot_t < plen,
                                prompts[req, jnp.clip(slot_t, 0, p_max - 1)],
                                state.last_tok)
-            logits, new_caches = decode_all(params, tok_in[:, None, None],
-                                            caches)
+            logits, new_caches, stats = decode_all(
+                params, tok_in[:, None, None], caches)
             next_tok = jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
 
         with jax.named_scope("serve.retire"):
@@ -404,7 +407,9 @@ def make_serve_step(model, cfg: ServingConfig, prompt_shape: Tuple[int, int],
                 # metric — and hence the served/degraded classification —
                 # forever)
                 queued=(jnp.sum(waiting & funded)
-                        - jnp.sum(admit)).astype(jnp.int32))
+                        - jnp.sum(admit)).astype(jnp.int32),
+                stats={k: jnp.sum(jnp.where(advance, v, 0)).astype(v.dtype)
+                       for k, v in sorted(stats.items())})
         return new_state, record
 
     def init_state(lane: ServeLane) -> ServeState:
@@ -444,6 +449,8 @@ class ServeResult:
                               #   the first token comes out at step
                               #   admit_step + prompt_len - 1
     wall_s: float = 0.0
+    stats: Dict[str, np.ndarray] = field(default_factory=dict)  # (T,)
+                              #   each: ``ServeRecord.stats`` per step
 
     @property
     def tokens_served(self) -> int:
@@ -500,7 +507,8 @@ def _result_from_device(state: ServeState, recs: ServeRecord,
         new_tokens=np.asarray(recs.new_tokens),
         queued=np.asarray(recs.queued),
         admit_step=np.asarray(state.admit_step),
-        wall_s=wall_s)
+        wall_s=wall_s,
+        stats={k: np.asarray(v) for k, v in recs.stats.items()})
 
 
 class ServingEngine:
@@ -529,6 +537,7 @@ class ServingEngine:
         self.prompts = jnp.asarray(prompts, jnp.int32)
         self.plan = plan
         self._programs: Dict[Tuple[bool, bool], Callable] = {}
+        self._executables: Dict[Tuple, Callable] = {}
 
     def _program(self, has_custody: bool, vmapped: bool) -> Callable:
         key = (has_custody, vmapped)
@@ -584,6 +593,21 @@ class ServingEngine:
                 f"{self.prompts.shape}, got {prompts.shape}")
         return prompts
 
+    @staticmethod
+    def _signature(*args) -> Tuple:
+        leaves, tree = jax.tree.flatten(args)
+        return tree, tuple((jnp.shape(x), jnp.result_type(x)) for x in leaves)
+
+    def compile(self, params, lane: ServeLane,
+                prompts: Optional[Array] = None) -> None:
+        """Compile :meth:`run`'s program for these arguments' shapes ahead of
+        the first episode; ``run`` then calls that executable for every lane
+        of the same shapes and types (others take the jitted program).  A
+        warm-up need not serve a whole episode."""
+        p = self._check(lane, prompts)
+        self._executables[self._signature(params, p, lane)] = self._program(
+            lane.custody is not None, False).lower(params, p, lane).compile()
+
     def run(self, params, lane: ServeLane,
             prompts: Optional[Array] = None) -> ServeResult:
         """Serve one lane.  Host spans (free when no profiler runs):
@@ -591,7 +615,8 @@ class ServingEngine:
         finish the episode, ``.readback`` for its results."""
         with jax.profiler.TraceAnnotation("repro.serve.run"):
             p = self._check(lane, prompts)
-            fn = self._program(lane.custody is not None, False)
+            fn = (self._executables.get(self._signature(params, p, lane))
+                  or self._program(lane.custody is not None, False))
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("repro.serve.wait"):
                 state, recs = jax.block_until_ready(fn(params, p, lane))

@@ -995,8 +995,10 @@ def run_campaign(loss_fn: Callable, params0, optimizer, data_fn: Callable,
     lanes are embarrassingly parallel; the decentralized mixing matmul is
     allclose only, see ``core/placement.py``), shared params over its
     within-lane ``data``/``model`` axes (allclose), and the one program
-    runs under the plan's mesh with ``spmd_axis_name`` on the campaign
-    vmap.
+    runs under the plan's mesh: a plan of lanes alone runs each device's
+    block of lanes under ``shard_map`` (no collective; the fused round's
+    Pallas kernels cannot be partitioned by XLA), a plan with within-lane
+    axes carries ``spmd_axis_name`` on the campaign vmap.
 
     Returns ``(final SwarmState, RoundRecord, final losses)`` with a leading
     run axis on every output leaf (RoundRecord leaves are (R, T, ...)).
@@ -1014,10 +1016,10 @@ def run_campaign(loss_fn: Callable, params0, optimizer, data_fn: Callable,
 
     def run_program():
         if fast_compile:
-            return fn.lower(lanes).compile(
+            return fn.lower(lanes, params0).compile(
                 compiler_options={
-                    "xla_backend_optimization_level": "0"})(lanes)
-        return fn(lanes)
+                    "xla_backend_optimization_level": "0"})(lanes, params0)
+        return fn(lanes, params0)
 
     if plan is None:
         return run_program()
@@ -1038,12 +1040,17 @@ def make_campaign_program(loss_fn: Callable, params0, optimizer,
                           fused: Optional[bool] = None,
                           plan: Optional[MeshPlan] = None) -> Callable:
     """Build (without running) THE campaign program — the jitted
-    ``fn(lanes)`` that :func:`run_campaign` executes.  ``lanes`` is
-    consulted for static structure only (N, decentralized/custody mode);
-    callers that place lanes on a mesh do so before/after as
-    :func:`run_campaign` does.  Split out so ``analysis.jaxpr_audit`` can
-    trace the *real* engine program — not a reimplementation that could
-    drift — and enforce its invariants statically."""
+    ``fn(lanes, params0)`` that :func:`run_campaign` executes.
+    ``lanes`` and ``params0`` are consulted here for static structure only (N,
+    decentralized/custody mode, the parameter tree); callers that place
+    lanes on a mesh do so before/after as :func:`run_campaign` does.  The
+    initial params are an argument of the program and every run's initial
+    state (optimizer state, ring, economy) is built inside it, so the
+    lowered module holds no parameter-sized constant and one compiled
+    program serves any initial params of the same shapes.  Split out so
+    ``analysis.jaxpr_audit`` can trace the *real* engine program — not a
+    reimplementation that could drift — and enforce its invariants
+    statically."""
     n = int(lanes.codes.shape[-1])
     decentralized = lanes.mixing is not None
     has_custody = lanes.custody is not None
@@ -1070,15 +1077,9 @@ def make_campaign_program(loss_fn: Callable, params0, optimizer,
             return jax.vmap(lambda i: data_fn(i, rnd))(jnp.arange(n))
     else:
         batch_fn = batched_data_fn
-    if decentralized:
-        state0 = init_decentralized_state(params0, optimizer, n,
-                                          staleness_bound=staleness_bound)
-    else:
-        state0 = init_state(params0, optimizer, n,
-                            staleness_bound=staleness_bound)
     user_eval = eval_fn
 
-    def one_run(lane):
+    def one_run(lane, state0):
         st0 = (state0._replace(econ=economy.init_econ_state(lane.econ, n))
                if has_econ else state0)
         efn = None
@@ -1097,9 +1098,32 @@ def make_campaign_program(loss_fn: Callable, params0, optimizer,
                 return jnp.stack([honest, extracted])
         return scan_rounds(round_fn, lane, st0, rounds, batch_fn, efn)
 
-    vmapped = (jax.vmap(one_run) if plan is None
-               else jax.vmap(one_run, spmd_axis_name=plan.lanes_axis))
-    return jax.jit(vmapped)
+    if plan is None:
+        vmapped = jax.vmap(one_run, in_axes=(0, None))
+    elif plan.data_devices == plan.model_devices == 1:
+        # lanes only: each device runs its own block of lanes, manual over
+        # the lane axis — nothing is exchanged, and the round's Pallas
+        # kernels (which XLA cannot partition) see per-device shapes.  No
+        # value crosses lanes, so the varying-axis typing is not needed.
+        lanes_spec = jax.sharding.PartitionSpec(plan.lanes_axis)
+        vmapped = jax.shard_map(
+            jax.vmap(one_run, in_axes=(0, None)), mesh=plan.mesh,
+            in_specs=(lanes_spec, jax.sharding.PartitionSpec()),
+            out_specs=lanes_spec, check_vma=False)
+    else:
+        vmapped = jax.vmap(one_run, in_axes=(0, None),
+                           spmd_axis_name=plan.lanes_axis)
+
+    def program(lanes, params0):
+        if decentralized:
+            state0 = init_decentralized_state(
+                params0, optimizer, n, staleness_bound=staleness_bound)
+        else:
+            state0 = init_state(params0, optimizer, n,
+                                staleness_bound=staleness_bound)
+        return vmapped(lanes, state0)
+
+    return jax.jit(program)
 
 
 def history_from_records(recs: RoundRecord, node_ids: Sequence[str], *,

@@ -179,6 +179,43 @@ def cache_insert(k_cache: Array, v_cache: Array, k_new: Array, v_new: Array, pos
     return k_cache, v_cache
 
 
+# -- multi-head latent attention (DeepSeek-V2) --------------------------------
+def mla_attention(q: Array, k: Array, v: Array, scale: float) -> Array:
+    """Full-sequence causal attention whose values are narrower than its
+    queries and keys: q, k (B, S, H, dqk), v (B, S, H, dv) -> (B, S, H, dv).
+    Scores and softmax in f32.  It holds the (S, S) scores: it serves the
+    training loss and ``prefill``; the served path is
+    :func:`mla_decode_attention`."""
+    s = jnp.einsum("bqhe,bkhe->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    pos = jnp.arange(q.shape[1])
+    s = jnp.where(pos[:, None] >= pos[None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhe->bqhe", p, v.astype(jnp.float32))
+    return out.astype(v.dtype)
+
+
+def mla_decode_attention(q_cat: Array, ckv: Array, pos: Array,
+                         scale: float) -> Array:
+    """The absorbed decode's attention over the latent cache.
+
+    ``q_cat`` (B, 1, H, r + rope) is each head's query in the cache's
+    coordinates: the nope query absorbed into the latent (q_nope W_UK^T)
+    beside the roped query.  ``ckv`` (B, L, r + rope) holds per position the
+    normed latent and the roped key all heads share, so one product gives
+    (q_nope W_UK^T) . c + q_rope . k_rope.  The operands stay in the cache's
+    type; scores and softmax are f32.  Returns p . ckv (B, 1, H, r + rope)
+    f32, whose first r columns are the latent output (the last rope columns
+    are the price of not slicing the cache)."""
+    s = jnp.einsum("bshc,btc->bhst", q_cat.astype(ckv.dtype), ckv,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(ckv.shape[1]) <= pos
+    s = jnp.where(valid, s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhst,btc->bshc", p.astype(ckv.dtype), ckv,
+                      preferred_element_type=jnp.float32)
+
+
 def reference_attention(q, k, v, *, causal=True, window=None):
     """Naive O(S^2) oracle used by tests (and kernels/swa_attention/ref.py)."""
     b, sq, hq, hd = q.shape

@@ -1,6 +1,8 @@
 """Shared building blocks for the model zoo (pure JAX, functional)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,6 +52,44 @@ def apply_rope(x: Array, positions: Array, theta: float) -> Array:
     angles = positions[..., None].astype(jnp.float32) * freqs        # (..., S, hd/2)
     angles = angles[..., None, :]                                    # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's temperature factor: 0.1 mscale ln(factor) + 1 above a factor
+    of 1 (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies of a ``dim``-wide rotary part: dimensions
+    that turn more than ``beta_fast`` times over the original context keep
+    theta^(-2i/dim), those that turn fewer than ``beta_slow`` times are
+    divided by ``factor``, with a linear ramp between."""
+    expo = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** expo
+    inter = 1.0 / (yarn.factor * theta ** expo)
+
+    def turns_at(rotations):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_at(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope_rotate(x: Array, positions: Array, inv_freq, scale: float = 1.0) -> Array:
+    """Rotate-half RoPE with given inverse frequencies, cos and sin times
+    ``scale``.  x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+    freqs = jnp.asarray(inv_freq, jnp.float32)                       # (hd/2,)
+    angles = positions[..., None].astype(jnp.float32) * freqs        # (..., S, hd/2)
+    angles = angles[..., None, :]                                    # (..., S, 1, hd/2)
+    cos, sin = jnp.cos(angles) * scale, jnp.sin(angles) * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

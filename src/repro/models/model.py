@@ -58,6 +58,17 @@ class Model:
     def decode_step(self, params, tokens, cache):
         return _family_module(self.cfg).decode_step(params, self.cfg, tokens, cache)
 
+    def decode_step_stats(self, params, tokens, cache):
+        """``decode_step`` with the step's counters beside the cache:
+        ``(logits, cache, stats)``, ``stats`` a dict of per-step counts
+        (a model with held-expert layers counts the token-slots routed to
+        them, ``expert_slots``; empty for the others)."""
+        family = _family_module(self.cfg)
+        if hasattr(family, "decode_step_stats"):
+            return family.decode_step_stats(params, self.cfg, tokens, cache)
+        logits, cache = family.decode_step(params, self.cfg, tokens, cache)
+        return logits, cache, {}
+
     def init_cache(self, batch: int, seq_len: int):
         return _family_module(self.cfg).init_cache(self.cfg, batch, seq_len)
 

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import dense_init
+from repro.models.common import dense_init, init_swiglu, swiglu
 
 Array = jax.Array
 
@@ -133,3 +133,75 @@ def moe_ffn_reference(x: Array, params, *, top_k: int):
     onehot = jax.nn.one_hot(experts, e, dtype=h.dtype)                # (B,S,k,E)
     w = jnp.einsum("bske,bsk->bse", onehot, gates.astype(h.dtype))
     return jnp.einsum("bsed,bse->bsd", h, w).astype(x.dtype)
+
+
+# -- the held-expert layer (one device's share of an expert-parallel layer) ----
+def init_held_moe(key, d: int, f: int, num_experts: int, held: int,
+                  shared_f: int, dtype):
+    """The router over all ``num_experts``, the first ``held`` routed
+    experts, and (``shared_f`` > 0) the shared experts as one SwiGLU."""
+    ks = jax.random.split(key, 5)
+    out = {
+        "router": dense_init(ks[0], (d, num_experts), jnp.float32),
+        "w_gate": dense_init(ks[1], (held, d, f), dtype),
+        "w_up": dense_init(ks[2], (held, d, f), dtype),
+        "w_down": dense_init(ks[3], (held, f, d), dtype),
+    }
+    if shared_f:
+        out["shared"] = init_swiglu(ks[4], d, shared_f, dtype)
+    return out
+
+
+def held_gates(x: Array, router: Array, top_k: int, *, norm_topk: bool,
+               scaling: float):
+    """DeepSeek's gate: softmax over every router output in f32, the greedy
+    top ``top_k``, renormalized only if ``norm_topk``, times ``scaling``.
+    Returns (gates (..., k) f32, experts (..., k) int32)."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        router.astype(jnp.float32))
+    gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True),
+                                    1e-9)
+    return gates * scaling, experts
+
+
+def held_moe_ffn(x: Array, params, *, held: Array, top_k: int,
+                 norm_topk: bool = True, scaling: float = 1.0):
+    """The part of an expert layer that one device of an expert-parallel
+    deployment computes: x (..., d) -> (out (..., d), token-slots routed to
+    the held experts).
+
+    The router keeps its full width: every token picks its ``top_k`` of all
+    the router's experts.  Only the experts ``held`` (ids into the router's
+    outputs, matching the leading axis of the expert weights) are computed,
+    for the tokens routed to them, each weighted by its gate; a token's
+    routes to experts held elsewhere add nothing here (the exchange that
+    would add them is not simulated).  The shared experts run on every
+    token.  Summing the outputs of devices that hold disjoint shares, with
+    the shared part counted once, gives the whole layer.
+
+    Drop-free by construction: every held expert runs on every token and
+    the result is masked by the token's gate for it (zero where not
+    routed), so no capacity limits how many tokens an expert takes, however
+    uneven the routing.  At decode sizes (one token per slot) that costs
+    less than gathering; a long prefill would want a grouped dispatch.
+    """
+    with jax.named_scope("model.moe.route"):
+        gates, experts = held_gates(x, params["router"], top_k,
+                                    norm_topk=norm_topk, scaling=scaling)
+        hit = experts[..., None] == held                    # (..., k, held)
+        weight = jnp.sum(jnp.where(hit, gates[..., None], 0.0), axis=-2)
+        routed = jnp.sum(hit).astype(jnp.int32)
+    with jax.named_scope("model.moe.experts"):
+        g = jnp.einsum("...d,edf->...ef", x, params["w_gate"])
+        u = jnp.einsum("...d,edf->...ef", x, params["w_up"])
+        y = jnp.einsum("...ef,efd->...ed", jax.nn.silu(g) * u,
+                       params["w_down"])
+        out = jnp.einsum("...ed,...e->...d", y.astype(jnp.float32), weight)
+        out = out.astype(x.dtype)
+    if "shared" in params:
+        with jax.named_scope("model.moe.shared"):
+            s = params["shared"]
+            out = out + swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
+    return out, routed

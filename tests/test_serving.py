@@ -337,3 +337,51 @@ def test_serving_grids_registered():
             "serving_smoke"} <= set(names)
     with pytest.raises(KeyError, match="serving_smoke"):
         get_serving_grid("nope")
+
+
+def test_compile_ahead_serves_like_the_jitted_program(serve_model, workload):
+    """``ServingEngine.compile`` compiles ``run``'s program before the first
+    episode: ``run`` then calls that executable, compiles nothing, and
+    serves exactly what the jitted program serves."""
+    _, model, params = serve_model
+    prompts, plens = workload
+    cfg = serving.ServingConfig(slots=3, max_new=4, steps=30)
+    lanes = [serving.build_lane(n_requests=6, prompt_lens=plens,
+                                max_new=[4, 2, 3, 1, 4, 2], steps=30,
+                                n_nodes=2, balances=[50.0], load=load)
+             for load in (0.5, 2.0)]
+    want = [serving.ServingEngine(model, cfg, prompts).run(params, lane)
+            for lane in lanes]
+    engine = serving.ServingEngine(model, cfg, prompts)
+    engine.compile(params, lanes[0])
+    got = [engine.run(params, lane) for lane in lanes]
+    # the jitted program was lowered, never called: nothing traced or
+    # compiled in ``run``
+    assert engine.program(has_custody=False, vmapped=False)._cache_size() == 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.tokens, w.tokens)
+        np.testing.assert_array_equal(g.n_active, w.n_active)
+
+
+def test_compile_ahead_serves_other_shapes_with_the_jitted_program(
+        serve_model, workload):
+    """An executable compiled ahead serves only its own argument shapes: a
+    lane of another signature (here one with custody) takes the jitted
+    program and serves what a fresh engine serves.  A dense model's
+    episode carries no per-step counters."""
+    _, model, params = serve_model
+    prompts, plens = workload
+    cfg = serving.ServingConfig(slots=3, max_new=4, steps=30)
+    kw = dict(n_requests=6, prompt_lens=plens, max_new=[4, 2, 3, 1, 4, 2],
+              steps=30, n_nodes=2, balances=[50.0], load=0.5)
+    plain = serving.build_lane(**kw)
+    held = serving.build_lane(**kw, custody=np.ones((2, 2), bool))
+    engine = serving.ServingEngine(model, cfg, prompts)
+    engine.compile(params, plain)
+    got = engine.run(params, held)
+    want = serving.ServingEngine(model, cfg, prompts).run(params, held)
+    np.testing.assert_array_equal(got.tokens, want.tokens)
+    np.testing.assert_array_equal(got.coverage, want.coverage)
+    assert engine.program(has_custody=True, vmapped=False)._cache_size() == 1
+    assert got.stats == {} and engine.run(params, plain).stats == {}
+
